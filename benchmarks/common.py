@@ -5,10 +5,8 @@ from __future__ import annotations
 import json
 import os
 import pathlib
-import time
 from typing import Callable, Dict, List
 
-import jax
 import numpy as np
 
 # BENCH_OUT_DIR overrides the artifact directory (the smoke-test lane points
@@ -116,16 +114,3 @@ def emit(name: str, rows: List[dict], derived: str = "",
     (OUT_DIR / f"{name}.json").write_text(json.dumps(doc, indent=1))
     print(f"{name},-,{derived}")
 
-
-def timed(fn: Callable, *args, warmup: int = 1, iters: int = 3):
-    for _ in range(warmup):
-        r = fn(*args)
-    jax.block_until_ready(r) if hasattr(r, "block_until_ready") else None
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        r = fn(*args)
-    try:
-        jax.block_until_ready(r)
-    except Exception:
-        pass
-    return (time.perf_counter() - t0) / iters * 1e6, r  # us per call

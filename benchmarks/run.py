@@ -64,6 +64,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.core import policies as policy_registry
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
 
     from . import (efficiency, fig3, fig4, fig5, fig_churn, fig_decode,
                    fig_fleet, fig_transport, kernel_bench, overhead,

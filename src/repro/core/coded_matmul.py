@@ -132,14 +132,12 @@ def run(
     if mesh is None:
         return local(a, x, idx, mask)
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(a, x, idx, mask)
 

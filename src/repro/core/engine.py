@@ -79,8 +79,7 @@ def _check_inputs(keys, R):
             "keys must be a non-empty batch of PRNG keys — e.g. "
             f"simulator.batch_keys(reps) — got shape {tuple(keys.shape)}"
         )
-    typed = hasattr(jax.dtypes, "prng_key") and jnp.issubdtype(
-        keys.dtype, jax.dtypes.prng_key)
+    typed = jnp.issubdtype(keys.dtype, jax.dtypes.prng_key)
     if not (typed and keys.ndim == 1) and not (
             keys.ndim == 2 and keys.shape[-1] == 2):
         raise ValueError(
@@ -586,7 +585,6 @@ def _sharded_batch_fn(cfg, R: int, M: int, policy, devs: tuple, batch: int):
     mesh of ``devs`` and each device vmaps its shard through ``_sim_one``
     — per-rep lanes are independent, so no collectives and results are
     identical to the single-device vmap."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from ..parallel import sharding as shd
@@ -594,8 +592,8 @@ def _sharded_batch_fn(cfg, R: int, M: int, policy, devs: tuple, batch: int):
     mesh = shd.data_mesh(devs)
     spec = shd.batch_spec(mesh, batch, extra_dims=1)
     body = lambda k: jax.vmap(lambda kk: _sim_one(kk, cfg, R, M, policy))(k)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,),
-                   out_specs=PartitionSpec("data"), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                       out_specs=PartitionSpec("data"), check_vma=False)
     return jax.jit(fn)
 
 
@@ -621,7 +619,6 @@ def _fleet_sharded_batch_fn(cfg, R: int, M: int, policy, fleet, devs: tuple,
     ``_fleet_one``.  Reps are independent (every tenant of a rep lives on
     that rep's device), so there are no collectives and the sharded run
     is bitwise the single-device ``_fleet_batch_jit`` vmap."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from ..parallel import sharding as shd
@@ -630,8 +627,8 @@ def _fleet_sharded_batch_fn(cfg, R: int, M: int, policy, fleet, devs: tuple,
     spec = shd.batch_spec(mesh, batch, extra_dims=1)
     body = lambda k: jax.vmap(
         lambda kk: _fleet_one(kk, cfg, R, M, policy, fleet))(k)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,),
-                   out_specs=PartitionSpec("data"), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                       out_specs=PartitionSpec("data"), check_vma=False)
     return jax.jit(fn)
 
 

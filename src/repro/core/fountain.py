@@ -500,9 +500,14 @@ def decode(
 ) -> Tuple[jnp.ndarray, str]:
     """Decode received coded blocks back to the R source blocks.
 
-    Tries O(R) peeling first; falls back to dense least-squares (Gaussian
-    elimination) over the real generator rows — always succeeds when the
-    received rows span the source space. Returns (blocks, method).
+    Tries O(R) peeling first; falls back to dense least-squares over the
+    real generator rows — always succeeds when the received rows span the
+    source space. Returns (blocks, method).
+
+    The least-squares solve is pinv(G) @ coded: the small (R, n_rx)
+    pseudo-inverse is formed on the host in float64, and only its product
+    with the payload runs on the device, at full f32 precision (a TPU's
+    default f32 matmul rounds its inputs to bf16).
     """
     plan = peel_decode_plan(code, received_ids)
     if plan is not None:
@@ -510,8 +515,9 @@ def decode(
     G = code.dense_generator()[np.asarray(received_ids)]  # (n_rx, R)
     if np.linalg.matrix_rank(G) < code.R:
         raise ValueError("received blocks do not span the source space")
-    flat = coded_rx.reshape(coded_rx.shape[0], -1)
-    sol = jnp.linalg.lstsq(jnp.asarray(G), flat)[0]
+    flat = coded_rx.reshape(coded_rx.shape[0], -1).astype(jnp.float32)
+    pinv = jnp.asarray(np.linalg.pinv(G.astype(np.float64)), jnp.float32)
+    sol = jnp.matmul(pinv, flat, precision=jax.lax.Precision.HIGHEST)
     return sol.reshape((code.R,) + coded_rx.shape[1:]).astype(coded_rx.dtype), "dense"
 
 

@@ -2,6 +2,9 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b --smoke \
       --requests 16 --batch 4 --prompt-len 16 --new-tokens 8 --replicas 2
+
+Full width on one chip needs bf16 params and compute (``--dtype bfloat16``):
+phi4-mini-3.8b holds 7.7 GB of weights in bf16, twice that in f32.
 """
 
 import argparse
@@ -22,6 +25,9 @@ def main():
                     help="artificial delay (s) on odd replicas — demo of CCP "
                          "dispatch over heterogeneous replicas")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "bfloat16"),
+                    help="param and compute dtype")
     args = ap.parse_args()
 
     import time
@@ -31,11 +37,14 @@ def main():
 
     from repro.configs import get_config
     from repro.models import build_model
-    from repro.runtime.serve_loop import CCPDispatcher, ServeEngine
+    from repro.runtime.serve_loop import CCPDispatcher, ServeEngine, init_params
+    from repro.utils import compile_cache
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    compile_cache.enable()
+    cfg = get_config(args.arch, smoke=args.smoke, param_dtype=args.dtype,
+                     compute_dtype=args.dtype)
     model = build_model(cfg)
-    params, _ = model.init(jax.random.PRNGKey(args.seed))
+    params = init_params(model, args.seed)
     engine = ServeEngine(model, params, max_len=args.max_len)
 
     rng = np.random.default_rng(args.seed)

@@ -59,7 +59,9 @@ def main():
     from repro.optim import adamw
     from repro.parallel import sharding as shd
     from repro.runtime.train_loop import make_coded_train_step, make_train_step
+    from repro.utils import compile_cache
 
+    compile_cache.enable()
     overrides = {}
     for kv in filter(None, os.environ.get("REPRO_TRAIN_OVERRIDES", "").split(",")):
         k, v = kv.split("=")

@@ -33,11 +33,8 @@ def _maybe_gather_kv(ck, cv):
     from jax.sharding import PartitionSpec as P
 
     p = P(spec if spec else None, None, None, None)
-    try:
-        return (jax.lax.with_sharding_constraint(ck, p),
-                jax.lax.with_sharding_constraint(cv, p))
-    except Exception:
-        return ck, cv
+    return (jax.lax.with_sharding_constraint(ck, p),
+            jax.lax.with_sharding_constraint(cv, p))
 
 
 def init_attention(pb: ParamBuilder, cfg: ModelConfig) -> Dict[str, Any]:

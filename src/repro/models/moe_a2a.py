@@ -111,13 +111,11 @@ def moe_block_a2a(
         aux = m.n_experts * jnp.sum(mean_probs * frac)
         return out.reshape(B_loc, T, D), aux[None]
 
-    from jax.experimental.shard_map import shard_map
-
     zero = jnp.zeros((1, 1), x.dtype)
     sg = params.get("shared_gate", zero)
     su = params.get("shared_up", zero)
     sd = params.get("shared_down", zero)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -129,7 +127,7 @@ def moe_block_a2a(
             P(data_axis, None, None),           # x (B, T, D)
         ),
         out_specs=(P(data_axis, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(params["router"], params["w_gate"], params["w_up"],
                   params["w_down"], sg, su, sd, x)

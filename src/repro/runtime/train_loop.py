@@ -163,14 +163,12 @@ def make_coded_train_step(
         loss = jax.lax.psum(model.loss_fn(params, own) * w_own, axis)
         return summed, loss
 
-    from jax.experimental.shard_map import shard_map
-
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_grads,
         mesh=mesh,
         in_specs=(P(), P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def train_step(params, opt_state, batch_all, weights):

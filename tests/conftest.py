@@ -1,14 +1,9 @@
 """Suite-level setup.
 
-* Puts ``src/`` on ``sys.path`` so the suite runs without PYTHONPATH=src.
-* Installs the vendored deterministic hypothesis shim
-  (``tests/_hypothesis_compat.py``) when the real ``hypothesis`` is absent —
-  the CI container has no network, so the property-test modules must collect
-  offline.
+Puts ``src/`` on ``sys.path`` so the suite runs without PYTHONPATH=src.
 """
 
 import gc
-import importlib.util
 import pathlib
 import sys
 
@@ -18,6 +13,7 @@ _ROOT = pathlib.Path(__file__).resolve().parent.parent
 _SRC = str(_ROOT / "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
+
 
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_between_modules():
@@ -36,14 +32,3 @@ def _clear_jax_caches_between_modules():
 
     jax.clear_caches()
 
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", pathlib.Path(__file__).parent / "_hypothesis_compat.py"
-    )
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies

@@ -22,7 +22,7 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.configs import get_config
     from repro.core import coded_matmul as cm
@@ -86,7 +86,7 @@ SCRIPT = textwrap.dedent(
 
     ce = shard_map(local_ce, mesh=mesh_v,
                    in_specs=(P(None, None, "model"), P()),
-                   out_specs=P(), check_rep=False)(logits, labels)
+                   out_specs=P(), check_vma=False)(logits, labels)
     out["sharded_ce_err"] = abs(float(ce) - dense)
     print("RESULT:" + json.dumps(out))
     """

@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.runtime.serve_loop import CCPDispatcher, ServeEngine
+from repro.runtime.serve_loop import CCPDispatcher, ServeEngine, init_params
 
 
 @pytest.fixture(scope="module")
@@ -41,6 +41,35 @@ def test_generate_matches_forward_argmax(engine):
     logits = eng.model.forward(eng.params, jnp.asarray(prompts))
     expect = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
     np.testing.assert_array_equal(out[:, 0], expect)
+
+
+def test_generate_logits_match_teacher_forced_forward(engine):
+    """The logits each greedy token was picked from equal an uncached
+    forward over prompt + generated tokens at the same positions."""
+    eng, cfg = engine
+    prompts = np.random.default_rng(3).integers(
+        0, cfg.vocab, size=(2, 8)).astype(np.int32)
+    out, logits = eng.generate(prompts, n_new=5, return_logits=True)
+    assert logits.shape == (2, 5, cfg.vocab)
+    np.testing.assert_array_equal(out, eng.generate(prompts, n_new=5))
+    np.testing.assert_array_equal(out, np.asarray(logits.argmax(-1)))
+    full = np.concatenate([prompts, out], axis=1)
+    ref = eng.model.forward(eng.params, full)[:, 7:12]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_init_params_matches_eager_init():
+    """The jitted init draws the same params as ``model.init``."""
+    cfg = get_config("phi4-mini-3.8b", smoke=True, param_dtype="bfloat16",
+                     compute_dtype="bfloat16")
+    model = build_model(cfg)
+    eager, _ = model.init(jax.random.PRNGKey(4))
+    jitted = init_params(model, seed=4)
+    for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(jitted)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
 
 
 def test_dispatcher_shifts_load_to_fast_replica(engine):
