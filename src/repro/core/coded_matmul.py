@@ -20,6 +20,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels.coded_matmul import coded_matmul as coded_matmul_op
+from ..utils import tracing
 from . import fountain
 
 __all__ = ["CodedMatmulPlan", "plan_coded_matmul", "device_blocks", "run", "recover"]
@@ -147,14 +148,22 @@ def recover(
     out: jnp.ndarray,           # (S*Bp*bm, n_dim) coded results
     survivors: np.ndarray,      # shard ids that returned
 ) -> jnp.ndarray:
-    """Collector-side recovery of y = A x from surviving shards only."""
+    """Collector-side recovery of y = A x from surviving shards only.
+
+    One ``recover`` span (attrs ``n_rx``, ``path``) per call: its child
+    ``recover.gather`` covers the survivors' slices and their concatenation
+    (over a mesh, where the cross-chip gather is enqueued), and
+    ``fountain.decode`` adds its own."""
     Bp, bm = plan.blocks_per_shard, plan.bm
-    rows = []
-    ids = []
-    for s in survivors:
-        sl = out[s * Bp * bm : (s + 1) * Bp * bm]
-        rows.append(sl.reshape(Bp, bm, -1))
-        ids.extend(plan.placement[s].tolist())
-    coded_rx = jnp.concatenate(rows, axis=0)  # (n_rx, bm, n_dim)
-    dec, _ = fountain.decode(coded_rx, plan.code, np.asarray(ids))
-    return dec.reshape(plan.code.R * bm, -1)
+    with tracing.span("recover", n_rx=len(survivors) * Bp) as attrs:
+        with tracing.span("recover.gather"):
+            rows = []
+            ids = []
+            for s in survivors:
+                sl = out[s * Bp * bm : (s + 1) * Bp * bm]
+                rows.append(sl.reshape(Bp, bm, -1))
+                ids.extend(plan.placement[s].tolist())
+            coded_rx = jnp.concatenate(rows, axis=0)  # (n_rx, bm, n_dim)
+        dec, attrs["path"] = fountain.decode(coded_rx, plan.code,
+                                             np.asarray(ids))
+        return dec.reshape(plan.code.R * bm, -1)

@@ -49,6 +49,7 @@ from . import decode as decode_mod
 from . import policies as policies_mod
 from . import simulator as sim
 from . import transport as transport_mod
+from ..utils import tracing
 
 __all__ = ["Engine", "RunResult", "FleetRunResult", "policy_stream"]
 
@@ -402,51 +403,62 @@ def policy_stream(beta, d_up, d_ack, d_down, policy, cfg_static,
 # ---------------------------------------------------------------------------
 
 def _sim_one(key, cfg, R: int, M: int, policy) -> Dict[str, jnp.ndarray]:
-    """Full single-rep pipeline as a traceable function of ``key``."""
-    k_h, k_p = jax.random.split(key)
-    mu, a, rate = sim.draw_helpers(k_h, cfg)
-    beta, d_up, d_ack, d_down = sim.draw_packet_tables(
-        k_p, cfg, mu, a, rate, M, R)
+    """Full single-rep pipeline as a traceable function of ``key``.
+
+    Its three stages run under the named scopes ``draws``,
+    ``policy_stream`` and ``completion``, which a profile reads back from
+    each compiled operation's ``op_name``."""
+    with jax.named_scope("draws"):
+        k_h, k_p = jax.random.split(key)
+        mu, a, rate = sim.draw_helpers(k_h, cfg)
+        beta, d_up, d_ack, d_down = sim.draw_packet_tables(
+            k_p, cfg, mu, a, rate, M, R)
     c = cfg.ccp_cfg(R)
     cfg_static = (c.Bx, c.Br, c.Back, c.alpha)
-    aux = policy.prepare(cfg, R, c, mu, a, rate)
-    if cfg.churn is None:
-        outs, psum = policy_stream(beta, d_up, d_ack, d_down, policy=policy,
-                                   cfg_static=cfg_static, aux=aux)
-        tx_end = None
-    else:
-        k_c = jax.random.fold_in(key, 0xC0DE)
-        dyn = sim.draw_dynamics(k_c, cfg, M)
-        outs, psum = policy_stream(
-            beta, d_up, d_ack, d_down, policy=policy, cfg_static=cfg_static,
-            churn_static=cfg.churn.static_key(), dyn=dyn, a=a, aux=aux,
-        )
-        tx_end = outs["tx_end"]
+    with jax.named_scope("policy_stream"):
+        aux = policy.prepare(cfg, R, c, mu, a, rate)
+        if cfg.churn is None:
+            outs, psum = policy_stream(beta, d_up, d_ack, d_down,
+                                       policy=policy, cfg_static=cfg_static,
+                                       aux=aux)
+            tx_end = None
+        else:
+            with jax.named_scope("draws"):
+                k_c = jax.random.fold_in(key, 0xC0DE)
+                dyn = sim.draw_dynamics(k_c, cfg, M)
+            outs, psum = policy_stream(
+                beta, d_up, d_ack, d_down, policy=policy,
+                cfg_static=cfg_static, churn_static=cfg.churn.static_key(),
+                dyn=dyn, a=a, aux=aux,
+            )
+            tx_end = outs["tx_end"]
     kk = R + cfg.K(R)
-    t, valid = policy.finalize(outs, aux, cfg, R, kk, tx_end)
-    mask = policy.packet_mask(aux, cfg.N, M)
-    if mask is None:
-        tr_eff, idle_eff, beta_eff = outs["tr"], outs["idle"], outs["beta"]
-    else:
-        # Block policies: packets beyond the assigned block do not exist
-        # physically — exclude them from the per-helper statistics.
-        tr_eff = jnp.where(mask, outs["tr"], jnp.inf)
-        idle_eff = jnp.where(mask, outs["idle"], 0.0)
-        beta_eff = jnp.where(mask, outs["beta"], 0.0)
-    eff = sim.efficiency_measured(tr_eff, idle_eff, beta_eff, t)
-    # isfinite guard: when t is +inf (an uncompletable block-policy rep)
-    # the inf sentinels in tr_eff must not count as delivered packets.
-    r_n = (jnp.isfinite(tr_eff) & (tr_eff <= t)).sum(axis=1)
-    max_backoff = outs["backoff"].max(axis=1)
-    # Loss rate over packets actually *sent*: a decoder-feedback policy that
-    # stops a stream early must not have its never-sent tail slots (lost =
-    # False by construction) dilute the reported rate.  Expressed as a
-    # rescale of mean() so always-sending policies (n_sent == M, scale
-    # exactly 1.0) stay bit-identical to the pre-PR-4 goldens.
-    n_sent = jnp.isfinite(outs["tx"]).sum(axis=1)
-    m_steps = outs["lost"].shape[1]
-    lost_frac = outs["lost"].mean(axis=1) * (
-        m_steps / jnp.maximum(n_sent, 1))
+    with jax.named_scope("completion"):
+        t, valid = policy.finalize(outs, aux, cfg, R, kk, tx_end)
+        mask = policy.packet_mask(aux, cfg.N, M)
+        if mask is None:
+            tr_eff, idle_eff, beta_eff = outs["tr"], outs["idle"], outs["beta"]
+        else:
+            # Block policies: packets beyond the assigned block do not exist
+            # physically — exclude them from the per-helper statistics.
+            tr_eff = jnp.where(mask, outs["tr"], jnp.inf)
+            idle_eff = jnp.where(mask, outs["idle"], 0.0)
+            beta_eff = jnp.where(mask, outs["beta"], 0.0)
+        eff = sim.efficiency_measured(tr_eff, idle_eff, beta_eff, t)
+        # isfinite guard: when t is +inf (an uncompletable block-policy rep)
+        # the inf sentinels in tr_eff must not count as delivered packets.
+        r_n = (jnp.isfinite(tr_eff) & (tr_eff <= t)).sum(axis=1)
+        max_backoff = outs["backoff"].max(axis=1)
+        # Loss rate over packets actually *sent*: a decoder-feedback policy
+        # that stops a stream early must not have its never-sent tail slots
+        # (lost = False by construction) dilute the reported rate.
+        # Expressed as a rescale of mean() so always-sending policies
+        # (n_sent == M, scale exactly 1.0) stay bit-identical to the
+        # recorded goldens.
+        n_sent = jnp.isfinite(outs["tx"]).sum(axis=1)
+        m_steps = outs["lost"].shape[1]
+        lost_frac = outs["lost"].mean(axis=1) * (
+            m_steps / jnp.maximum(n_sent, 1))
     res = dict(T=t, valid=valid, efficiency=eff, r_n=r_n, mu=mu, a=a,
                rate=rate, max_backoff=max_backoff, lost_frac=lost_frac)
     for k in getattr(policy, "report_aux", ()):
@@ -471,89 +483,96 @@ def _fleet_one(key, cfg, R: int, M: int, policy, fleet) -> Dict[str, jnp.ndarray
     """
     from . import fleet as fleet_mod  # deferred: fleet imports the kernels above
 
-    k_h, k_p = jax.random.split(key)
-    mu, a, rate = sim.draw_helpers(k_h, cfg)
     Tt = fleet.n_tasks
-    beta, d_up, d_ack, d_down = sim.draw_packet_tables_fleet(
-        k_p, cfg, mu, a, rate, Tt, M, R)
+    with jax.named_scope("draws"):
+        k_h, k_p = jax.random.split(key)
+        mu, a, rate = sim.draw_helpers(k_h, cfg)
+        beta, d_up, d_ack, d_down = sim.draw_packet_tables_fleet(
+            k_p, cfg, mu, a, rate, Tt, M, R)
     c = cfg.ccp_cfg(R)
     cfg_static = (c.Bx, c.Br, c.Back, c.alpha)
-    release = fleet_mod.draw_releases(jax.random.fold_in(key, 0xF7EE), fleet)
-    recruit, prio = fleet_mod.place(
-        jax.random.fold_in(key, 0xAD31), fleet, cfg, mu, a, rate)
+    with jax.named_scope("draws"):
+        release = fleet_mod.draw_releases(jax.random.fold_in(key, 0xF7EE),
+                                          fleet)
+        recruit, prio = fleet_mod.place(
+            jax.random.fold_in(key, 0xAD31), fleet, cfg, mu, a, rate)
     per_task_aux = policy.fleet_aux == "per_task"
-    if per_task_aux:
-        # Block policies: one aux per tenant so the fixed allocation
-        # lands on the tenant's recruited helpers (see Policy.prepare_fleet)
-        aux = policy.prepare_fleet(cfg, R, c, mu, a, rate, recruit)
-    else:
-        aux = policy.prepare(cfg, R, c, mu, a, rate)
-    if cfg.churn is None:
-        outs, psum = fleet_mod.fleet_stream(
-            beta, d_up, d_ack, d_down, release, recruit, prio,
-            policy=policy, cfg_static=cfg_static,
-            fleet_static=fleet.static_key(), aux=aux,
-            aux_task_axis=per_task_aux)
-        tx_end = None
-    else:
-        dyn = sim.draw_dynamics_fleet(
-            jax.random.fold_in(key, 0xC0DE), cfg, M, Tt)
-        outs, psum = fleet_mod.fleet_stream(
-            beta, d_up, d_ack, d_down, release, recruit, prio,
-            policy=policy, cfg_static=cfg_static,
-            fleet_static=fleet.static_key(),
-            churn_static=cfg.churn.static_key(), dyn=dyn, a=a, aux=aux,
-            aux_task_axis=per_task_aux)
-        tx_end = outs["tx_end"]
-    kk = R + cfg.K(R)
-    if per_task_aux:
-        mask = jax.vmap(lambda at: policy.packet_mask(at, cfg.N, M))(aux)
-    else:
-        mask = policy.packet_mask(aux, cfg.N, M)
-    per_keys = ("tr", "idle", "tx", "arrive", "beta", "lost", "backoff")
-    if policy.uses_decoder:
-        per_keys += ("sym_id",)
-    task_outs = {k: outs[k] for k in per_keys}
-
-    def _finish(outs_t, tx_end_t, aux_t, mask_t):
-        # Per-task completion + per-helper statistics: the same extraction
-        # as _sim_one, vmapped over the task axis (aux/mask mapped per
-        # task for fleet_aux == "per_task" block policies, else shared).
-        t, valid = policy.finalize(outs_t, aux_t, cfg, R, kk, tx_end_t)
-        if mask_t is None:
-            tr_eff, idle_eff, beta_eff = (
-                outs_t["tr"], outs_t["idle"], outs_t["beta"])
+    with jax.named_scope("policy_stream"):
+        if per_task_aux:
+            # Block policies: one aux per tenant so the fixed allocation
+            # lands on the tenant's recruited helpers (see
+            # Policy.prepare_fleet)
+            aux = policy.prepare_fleet(cfg, R, c, mu, a, rate, recruit)
         else:
-            tr_eff = jnp.where(mask_t, outs_t["tr"], jnp.inf)
-            idle_eff = jnp.where(mask_t, outs_t["idle"], 0.0)
-            beta_eff = jnp.where(mask_t, outs_t["beta"], 0.0)
-        eff = sim.efficiency_measured(tr_eff, idle_eff, beta_eff, t)
-        r_n = (jnp.isfinite(tr_eff) & (tr_eff <= t)).sum(axis=1)
-        n_sent = jnp.isfinite(outs_t["tx"]).sum(axis=1)
-        m_steps = outs_t["lost"].shape[1]
-        lost_frac = outs_t["lost"].mean(axis=1) * (
-            m_steps / jnp.maximum(n_sent, 1))
-        return dict(T=t, valid=valid, efficiency=eff, r_n=r_n,
-                    max_backoff=outs_t["backoff"].max(axis=1),
-                    lost_frac=lost_frac)
+            aux = policy.prepare(cfg, R, c, mu, a, rate)
+        if cfg.churn is None:
+            outs, psum = fleet_mod.fleet_stream(
+                beta, d_up, d_ack, d_down, release, recruit, prio,
+                policy=policy, cfg_static=cfg_static,
+                fleet_static=fleet.static_key(), aux=aux,
+                aux_task_axis=per_task_aux)
+            tx_end = None
+        else:
+            with jax.named_scope("draws"):
+                dyn = sim.draw_dynamics_fleet(
+                    jax.random.fold_in(key, 0xC0DE), cfg, M, Tt)
+            outs, psum = fleet_mod.fleet_stream(
+                beta, d_up, d_ack, d_down, release, recruit, prio,
+                policy=policy, cfg_static=cfg_static,
+                fleet_static=fleet.static_key(),
+                churn_static=cfg.churn.static_key(), dyn=dyn, a=a, aux=aux,
+                aux_task_axis=per_task_aux)
+            tx_end = outs["tx_end"]
+    kk = R + cfg.K(R)
+    with jax.named_scope("completion"):
+        if per_task_aux:
+            mask = jax.vmap(lambda at: policy.packet_mask(at, cfg.N, M))(aux)
+        else:
+            mask = policy.packet_mask(aux, cfg.N, M)
+        per_keys = ("tr", "idle", "tx", "arrive", "beta", "lost", "backoff")
+        if policy.uses_decoder:
+            per_keys += ("sym_id",)
+        task_outs = {k: outs[k] for k in per_keys}
 
-    aux_ax = 0 if per_task_aux else None
-    if tx_end is None:
-        res = jax.vmap(lambda o, at, mt: _finish(o, None, at, mt),
-                       in_axes=(0, aux_ax, aux_ax))(task_outs, aux, mask)
-    else:
-        res = jax.vmap(_finish, in_axes=(0, 0, aux_ax, aux_ax))(
-            task_outs, tx_end, aux, mask)
-    res["release"] = release
-    res["sojourn"] = res["T"] - release
-    # Fleet-level metrics: helper utilization over the rep's makespan and
-    # Jain fairness over the valid tenants' sojourn times.
-    vmask = res["valid"] & jnp.isfinite(res["T"])
-    makespan = jnp.max(jnp.where(vmask, res["T"], -jnp.inf))
-    res["makespan"] = makespan
-    res["util"] = fleet_mod.helper_utilization(
-        outs["beta"], outs["tr"], d_down, makespan)
-    res["fairness"] = fleet_mod.jain_fairness(res["sojourn"], vmask)
+        def _finish(outs_t, tx_end_t, aux_t, mask_t):
+            # Per-task completion + per-helper statistics: the same extraction
+            # as _sim_one, vmapped over the task axis (aux/mask mapped per
+            # task for fleet_aux == "per_task" block policies, else shared).
+            t, valid = policy.finalize(outs_t, aux_t, cfg, R, kk, tx_end_t)
+            if mask_t is None:
+                tr_eff, idle_eff, beta_eff = (
+                    outs_t["tr"], outs_t["idle"], outs_t["beta"])
+            else:
+                tr_eff = jnp.where(mask_t, outs_t["tr"], jnp.inf)
+                idle_eff = jnp.where(mask_t, outs_t["idle"], 0.0)
+                beta_eff = jnp.where(mask_t, outs_t["beta"], 0.0)
+            eff = sim.efficiency_measured(tr_eff, idle_eff, beta_eff, t)
+            r_n = (jnp.isfinite(tr_eff) & (tr_eff <= t)).sum(axis=1)
+            n_sent = jnp.isfinite(outs_t["tx"]).sum(axis=1)
+            m_steps = outs_t["lost"].shape[1]
+            lost_frac = outs_t["lost"].mean(axis=1) * (
+                m_steps / jnp.maximum(n_sent, 1))
+            return dict(T=t, valid=valid, efficiency=eff, r_n=r_n,
+                        max_backoff=outs_t["backoff"].max(axis=1),
+                        lost_frac=lost_frac)
+
+        aux_ax = 0 if per_task_aux else None
+        if tx_end is None:
+            res = jax.vmap(lambda o, at, mt: _finish(o, None, at, mt),
+                           in_axes=(0, aux_ax, aux_ax))(task_outs, aux, mask)
+        else:
+            res = jax.vmap(_finish, in_axes=(0, 0, aux_ax, aux_ax))(
+                task_outs, tx_end, aux, mask)
+        res["release"] = release
+        res["sojourn"] = res["T"] - release
+        # Fleet-level metrics: helper utilization over the rep's makespan and
+        # Jain fairness over the valid tenants' sojourn times.
+        vmask = res["valid"] & jnp.isfinite(res["T"])
+        makespan = jnp.max(jnp.where(vmask, res["T"], -jnp.inf))
+        res["makespan"] = makespan
+        res["util"] = fleet_mod.helper_utilization(
+            outs["beta"], outs["tr"], d_down, makespan)
+        res["fairness"] = fleet_mod.jain_fairness(res["sojourn"], vmask)
     res.update(mu=mu, a=a, rate=rate)
     for k in getattr(policy, "report_aux", ()):
         res[f"x_{k}"] = aux[k]
@@ -808,7 +827,8 @@ class Engine:
     completion rule) over a batch of PRNG keys with one shared,
     power-of-two-bucketed horizon M and a single certification pass: if
     any rep is uncertified the shared horizon doubles and the whole batch
-    re-runs (one extra compile, amortized across the sweep).  With
+    re-runs (one extra compile, amortized across the sweep; each re-run
+    counts ``engine.horizon_rerun`` in ``repro.utils.tracing``).  With
     ``shard=True`` the key batch is additionally split across the local
     devices through ``shard_map`` on a 1-D 'data' mesh (padded to a
     device-count multiple); per-rep lanes never communicate, so sharded
@@ -840,6 +860,7 @@ class Engine:
             if bool(out["valid"].all()) or M >= cap or M_override is not None:
                 break
             M = min(M * 2, cap)
+            tracing.count("engine.horizon_rerun")
         res = {k: np.asarray(v) for k, v in out.items()}
         extras = {k[2:]: v for k, v in res.items() if k.startswith("x_")}
         core = {k: v for k, v in res.items() if not k.startswith("x_")}
@@ -901,6 +922,7 @@ class Engine:
             if bool(out["valid"].all()) or M >= cap or M_override is not None:
                 break
             M = min(M * 2, cap)
+            tracing.count("engine.horizon_rerun")
         res = {k: np.asarray(v) for k, v in out.items()}
         extras = {k[2:]: v for k, v in res.items() if k.startswith("x_")}
         core = {k: v for k, v in res.items() if not k.startswith("x_")}

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import tracing
+
 __all__ = [
     "ideal_soliton",
     "robust_soliton",
@@ -508,17 +510,34 @@ def decode(
     pseudo-inverse is formed on the host in float64, and only its product
     with the payload runs on the device, at full f32 precision (a TPU's
     default f32 matmul rounds its inputs to bf16).
+
+    Spans (``repro.utils.tracing``): ``decode.peel_plan``, then on the
+    dense path ``decode.rank``, ``decode.convert`` (the payload's f32
+    conversion), ``decode.pinv`` (host pinv and its upload) and
+    ``decode.solve`` (the device matmul and the reshapes); counters
+    ``decode.peel`` and ``decode.dense`` count the calls each path
+    finished.
     """
-    plan = peel_decode_plan(code, received_ids)
+    with tracing.span("decode.peel_plan"):
+        plan = peel_decode_plan(code, received_ids)
     if plan is not None:
+        tracing.count("decode.peel")
         return apply_decode_plan(coded_rx, plan), "peel"
-    G = code.dense_generator()[np.asarray(received_ids)]  # (n_rx, R)
-    if np.linalg.matrix_rank(G) < code.R:
-        raise ValueError("received blocks do not span the source space")
-    flat = coded_rx.reshape(coded_rx.shape[0], -1).astype(jnp.float32)
-    pinv = jnp.asarray(np.linalg.pinv(G.astype(np.float64)), jnp.float32)
-    sol = jnp.matmul(pinv, flat, precision=jax.lax.Precision.HIGHEST)
-    return sol.reshape((code.R,) + coded_rx.shape[1:]).astype(coded_rx.dtype), "dense"
+    with tracing.span("decode.rank"):
+        G = code.dense_generator()[np.asarray(received_ids)]  # (n_rx, R)
+        if np.linalg.matrix_rank(G) < code.R:
+            raise ValueError("received blocks do not span the source space")
+    # Enqueued first, so the device converts while the host forms the pinv.
+    with tracing.span("decode.convert"):
+        flat = coded_rx.reshape(coded_rx.shape[0], -1).astype(jnp.float32)
+    with tracing.span("decode.pinv"):
+        pinv = jnp.asarray(np.linalg.pinv(G.astype(np.float64)), jnp.float32)
+    with tracing.span("decode.solve"):
+        sol = jnp.matmul(pinv, flat, precision=jax.lax.Precision.HIGHEST)
+        dec = sol.reshape((code.R,) + coded_rx.shape[1:]).astype(
+            coded_rx.dtype)
+    tracing.count("decode.dense")
+    return dec, "dense"
 
 
 def decode_failure_prob(

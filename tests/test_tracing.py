@@ -1,0 +1,207 @@
+"""The program's flight recorder (``repro.utils.tracing``), its spans in the
+collector's recovery, its counter in the engine's horizon loop, and the
+simulator's named scopes in the compiled batch program."""
+
+import glob
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import coded_matmul as cm
+from repro.core import engine, simulator
+from repro.core.fleet import FleetConfig
+from repro.core.policies import get as get_policy
+from repro.utils import tracing
+
+
+def _spans(recs):
+    return sorted((r for r in recs if isinstance(r, tracing.Span)),
+                  key=lambda r: r.t0)
+
+
+def test_nested_spans_carry_parent_and_root_in_each_thread():
+    rec = tracing.Recorder()
+    seen = {}
+
+    def worker():
+        with rec.span("t.root") as attrs:
+            attrs["k"] = 1
+            with rec.span("t.child"):
+                pass
+
+    with rec.span("m.root", n=3):
+        with rec.span("m.child"):
+            # A thread started inside a span opens requests of its own.
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=30)
+            with rec.span("m.grandchild"):
+                pass
+    assert not th.is_alive()
+    for r in _spans(rec.records()):
+        seen[r.name] = r
+    m, mc, mg = seen["m.root"], seen["m.child"], seen["m.grandchild"]
+    assert (m.parent, m.root, m.attrs) == (None, m.id, {"n": 3})
+    assert (mc.parent, mc.root) == (m.id, m.id)
+    assert (mg.parent, mg.root) == (mc.id, m.id)
+    t, tc = seen["t.root"], seen["t.child"]
+    assert (t.parent, t.root, t.attrs) == (None, t.id, {"k": 1})
+    assert (tc.parent, tc.root) == (t.id, t.id)
+    assert len({m.id, mc.id, mg.id, t.id, tc.id}) == 5
+    assert m.t0 <= mc.t0 <= mg.t0 <= mg.t1 <= mc.t1 <= m.t1
+
+
+def test_counters_keep_point_events_and_totals():
+    rec = tracing.Recorder()
+    rec.count("a")
+    with rec.span("req"):
+        rec.count("a", 3)
+        rec.count("b", 2)
+    counts = [r for r in rec.records() if isinstance(r, tracing.Count)]
+    assert [(c.name, c.n) for c in counts] == [("a", 1), ("a", 3), ("b", 2)]
+    (req,) = _spans(rec.records())
+    assert [c.root for c in counts] == [None, req.id, req.id]
+    assert req.t0 <= counts[1].t <= req.t1
+    assert rec.totals() == {"a": 4, "b": 2}
+    # records(t0, t1) keeps what lies inside the interval.
+    inside = rec.records(req.t0, req.t1)
+    assert {r.name for r in inside} == {"req", "a", "b"}
+
+
+def test_the_buffer_is_bounded_and_totals_are_not():
+    rec = tracing.Recorder(maxlen=5)
+    for i in range(12):
+        rec.count("x", i)
+    recs = rec.records()
+    assert len(recs) == 5
+    assert [r.n for r in recs] == [7, 8, 9, 10, 11]
+    assert rec.totals() == {"x": sum(range(12))}
+
+
+def test_disable_records_nothing():
+    before = len(tracing.records())
+    totals = tracing.totals()
+    tracing.disable()
+    try:
+        with tracing.span("off") as attrs:
+            attrs["x"] = 1
+        with tracing.span("off2") as attrs2:
+            pass
+        tracing.count("off")
+        assert tracing.span("a") is tracing.span("b")
+        assert attrs2 == {}
+    finally:
+        tracing.enable()
+    assert len(tracing.records()) == before
+    assert tracing.totals() == totals
+
+
+def _dense_plan():
+    # R=64 source blocks, K=16 parities over 8 shards: no single-shard
+    # loss peels (the one-chip cell's code at a small block size).
+    return cm.plan_coded_matmul(rows=64 * 8, n_shards=8, overhead=0.25, bm=8)
+
+
+def _peel_plan():
+    return cm.plan_coded_matmul(rows=512, n_shards=8, overhead=0.25, bm=32)
+
+
+@pytest.mark.parametrize("make_plan,path,children", [
+    (_dense_plan, "dense", ["recover.gather", "decode.peel_plan",
+                            "decode.rank", "decode.convert", "decode.pinv",
+                            "decode.solve"]),
+    (_peel_plan, "peel", ["recover.gather", "decode.peel_plan"]),
+])
+def test_recover_spans_and_counter(make_plan, path, children):
+    plan = make_plan()
+    rows = plan.code.R * plan.bm
+    a = jax.random.normal(jax.random.PRNGKey(0), (rows, 16), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 4), jnp.float32)
+    out = cm.run(plan, a, x)
+    surv = np.arange(1, plan.n_shards)
+    cm.recover(plan, out, surv)                  # compile outside the look
+    t0 = time.perf_counter()
+    totals = tracing.totals()
+    y = cm.recover(plan, out, surv)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(a @ x), rtol=2e-3,
+                               atol=2e-3)
+    recs = tracing.records(t0)
+    roots = [r for r in _spans(recs) if r.name == "recover"]
+    assert len(roots) == 1
+    root = roots[0]
+    assert root.parent is None and root.root == root.id
+    assert root.attrs == {"n_rx": 7 * plan.blocks_per_shard, "path": path}
+    kids = [r for r in _spans(recs) if r.id != root.id]
+    assert [k.name for k in kids] == children
+    assert all(k.parent == root.id and k.root == root.id for k in kids)
+    assert all(root.t0 <= k.t0 <= k.t1 <= root.t1 for k in kids)
+    after = tracing.totals()
+    counter = f"decode.{path}"
+    assert after.get(counter, 0) - totals.get(counter, 0) == 1
+    other = "decode.peel" if path == "dense" else "decode.dense"
+    assert after.get(other, 0) == totals.get(other, 0)
+    (ev,) = [r for r in recs if isinstance(r, tracing.Count)]
+    assert (ev.name, ev.root) == (counter, root.id)
+
+
+def test_profiler_trace_holds_the_recover_span(tmp_path):
+    plan = _dense_plan()
+    a = jnp.ones((plan.code.R * plan.bm, 8), jnp.float32)
+    out = cm.run(plan, a, jnp.ones((8, 2), jnp.float32))
+    surv = np.arange(1, plan.n_shards)
+    cm.recover(plan, out, surv)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        cm.recover(plan, out, surv).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(tracing.PREFIX):
+                        found[ev.name] = dict(ev.stats)
+    assert found["c3p.recover"].get("n_rx") == 7 * plan.blocks_per_shard
+    assert {"c3p.recover.gather", "c3p.decode.pinv",
+            "c3p.decode.solve"} <= set(found)
+
+
+def test_horizon_reruns_are_counted(monkeypatch):
+    cfg = simulator.ScenarioConfig(N=5)
+    start = 16
+    monkeypatch.setattr(engine, "_initial_m", lambda *a, **k: start)
+    before = tracing.totals().get("engine.horizon_rerun", 0)
+    res = engine.Engine().run(cfg, "ccp", simulator.batch_keys(2), 60)
+    reruns = tracing.totals().get("engine.horizon_rerun", 0) - before
+    assert res.M > start
+    assert reruns == int(np.log2(res.M // start))
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("fleet", [False, True])
+def test_batch_program_carries_the_simulator_scopes(fleet):
+    cfg = simulator.ScenarioConfig(N=6)
+    keys = simulator.batch_keys(2)
+    policy = get_policy("ccp")
+    if fleet:
+        lowered = engine._fleet_batch_jit.lower(keys, cfg, 50, 32, policy,
+                                                FleetConfig())
+    else:
+        lowered = engine._sim_batch_jit.lower(keys, cfg, 50, 32, policy)
+    names = _OP_NAME.findall(lowered.compile().as_text())
+    parts = {re.sub(r"^(?:\w+\()+|\)+$", "", p)
+             for n in names for p in n.split("/")}
+    assert {"draws", "policy_stream", "completion"} <= parts
+    # The completion sort and the pacing scan's loop sit under their scopes.
+    assert any("completion" in n and "sort" in n for n in names)
+    assert any("policy_stream" in n and n.endswith("while") for n in names)
