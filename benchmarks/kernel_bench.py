@@ -53,7 +53,7 @@ def run() -> dict:
     a = jax.random.normal(jax.random.PRNGKey(0), (8 * 16, 64))
     x = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
     out = coded_matmul(a, x, jnp.asarray(code.idx), jnp.asarray(code.mask),
-                       bm=16, bk=32, bn=16, use_pallas=True, interpret=True)
+                       bm=16, use_pallas=True, interpret=True)
     ref = coded_matmul_ref(a, x, jnp.asarray(code.idx), jnp.asarray(code.mask), 16)
     max_err = float(jnp.abs(out - ref).max())
 

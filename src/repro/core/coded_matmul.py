@@ -96,15 +96,13 @@ def plan_coded_matmul(
     )
 
 
-def device_blocks(plan: CodedMatmulPlan, a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def device_blocks(plan: CodedMatmulPlan, a: jnp.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Gather per-device (idx, weights) tables in placement order:
     returns (idx (S*Bp, d_max), weights (S*Bp, d_max)) where row s*Bp+i is
-    the i-th coded block on shard s (weights = mask * Rademacher coef)."""
+    the i-th coded block on shard s (weights = mask * Rademacher coef).
+    Host arrays, so the kernel's wrapper can count their real pairs."""
     flat = plan.placement.reshape(-1)
-    return (
-        jnp.asarray(plan.code.idx[flat]),
-        jnp.asarray(plan.code.weights[flat]),
-    )
+    return plan.code.idx[flat], plan.code.weights[flat]
 
 
 def run(

@@ -4,81 +4,144 @@ The paper's helpers compute ``p_{n,i} @ x`` where ``p`` is a fountain-coded
 packet.  On TPU, the coded unit is an MXU-aligned row-block and the encode
 (a sparse ±1 combination of source blocks) is fused into the matmul:
 
-  for each coded block b, output tile n, reduction tile k:
-      acc_a  = sum_j mask[b,j] * A[idx[b,j], k-tile]     (VPU adds, VMEM)
-      acc_o += acc_a @ X[k-tile, n-tile]                 (MXU)
+  for each output column tile n, coded block b, real neighbour slot j:
+      acc_a  = sum_j w[b,j] * A[idx[b,j], :]       (VPU adds, f32 in VMEM)
+      out    = round(acc_a) @ X[:, n-tile]          (MXU, once per block)
 
-The gather over ``idx`` uses scalar prefetch: the neighbour table drives the
-``A`` BlockSpec index_map, so each A tile is DMA'd HBM->VMEM exactly once
-per (b, k, j) and the *encoded* matrix never materializes in HBM.  Vs.
-encode-then-matmul this saves a full HBM round trip of the coded A
-(write C*bm*K + read C*bm*K bytes).
+Grid ``(n_tiles, C, d_max)``.  An A block is a whole-K row block
+``(bm, k)``, so there is no reduction axis: one step per (coded block,
+neighbour slot) and one DMA per real pair.  The gather uses scalar
+prefetch: the neighbour table drives the A BlockSpec's index_map, and the
+*encoded* matrix never lands in HBM.
 
-Grid: (C, n_tiles, k_tiles, d_max) — j innermost so the fp32 VMEM
-accumulators live across the encode reduction; k next so output tiles
-accumulate across the matmul reduction.
+Padded slots.  ``coded_matmul_pallas`` compacts each block's real
+neighbours (``mask != 0``) to the front of its row, in their order, and
+fills the rest with the block's last real neighbour.  The index_map then
+repeats on a padded step, so Pallas issues no copy, and the kernel skips
+it (``j >= deg[b]``).  The dot runs at ``j == deg[b] - 1``; a block with no
+real slot writes zeros.
 
-VMEM working set per step: A tile (bm, bk) + X tile (bk, bn) + acc_a
-(bm, bk) f32 + acc_o (bm, bn) f32 + out tile — with the default
-bm=bk=bn=256 and bf16 inputs that is 256*256*(2+2+4+4+2) B ~ 0.9 MB, well
-inside the ~16 MB v5e VMEM budget; tiles are 128-aligned for the MXU.
+x is the same block for every step of an output tile, so it is read from
+HBM once per tile; ``tile_n`` makes the tile as wide as the VMEM budget
+allows (the whole of n at the coded FFN's widths), so x is read once a
+call.
+
+VMEM budget.  ``vmem_bytes`` counts the double-buffered A block, x tile
+and output tile, the f32 accumulator, its rounded copy and the dot's f32
+result.  ``tile_n`` picks the widest output tile under ``VMEM_BUDGET``
+that is a multiple of 128 dividing n (or n itself); ``VMEM_LIMIT`` lets
+Mosaic use more than its default scoped VMEM, well under a v5e core's
+128 MiB.  bf16 at k=3072, n=2048, bm=256 plans 35.5 MiB with one tile;
+f32 at k=n=4096 gets 512-wide tiles.
+
+Rounding.  The encoded block is accumulated in f32 and rounded once to
+the dtype of x before the dot, which accumulates in f32.  For bf16 inputs
+that is the rounding Mosaic's DEFAULT contraction of f32 operands applies
+anyway; f32 inputs keep an f32 block and ``Precision.HIGHEST``.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANE = 128
+VMEM_BUDGET = 48 << 20   # the working set a tile choice may plan for
+VMEM_LIMIT = 96 << 20    # Mosaic's scoped VMEM limit for the call
 
-def _kernel(idx_ref, mask_ref, a_ref, x_ref, o_ref, acc_a, acc_o, *, d_max, nk,
+
+def vmem_bytes(bm: int, k: int, bn: int, in_size: int, out_size: int) -> int:
+    """VMEM of one step at tile width ``bn`` (item sizes in bytes)."""
+    return (2 * bm * k * in_size        # A row block, double-buffered
+            + 2 * k * bn * in_size      # x tile, double-buffered
+            + 2 * bm * bn * out_size    # output tile, double-buffered
+            + bm * k * (4 + in_size)    # f32 accumulator and its rounding
+            + bm * bn * 4)              # the dot's f32 result
+
+
+def tile_n(bm: int, k: int, n: int, in_size: int, out_size: int,
+           cap: Optional[int] = None) -> int:
+    """Widest output tile under ``VMEM_BUDGET``: n itself or a multiple of
+    128 dividing n, at most ``cap`` (the narrowest such tile when none is
+    under it)."""
+    widths = sorted({n} | {w for w in range(LANE, n, LANE) if n % w == 0})
+    if cap is not None:
+        widths = [w for w in widths if w <= cap] or widths[:1]
+    fits = [w for w in widths
+            if vmem_bytes(bm, k, w, in_size, out_size) <= VMEM_BUDGET]
+    if not fits:
+        raise ValueError(
+            f"a whole-K row block (bm={bm}, k={k}) with an output tile of "
+            f"{widths[0]} needs {vmem_bytes(bm, k, widths[0], in_size, out_size)}"
+            f" B of VMEM, over the {VMEM_BUDGET} B budget")
+    return fits[-1]
+
+
+def _kernel(deg_ref, idx_ref, w_ref, a_ref, x_ref, o_ref, acc_a, *,
             precision):
-    j = pl.program_id(3)
-    k = pl.program_id(2)
+    b, j = pl.program_id(1), pl.program_id(2)
+    deg = deg_ref[b]
 
-    @pl.when(j == 0)
-    def _init_acc_a():
-        acc_a[...] = jnp.zeros_like(acc_a)
+    @pl.when(j < deg)
+    def _encode():
+        part = a_ref[...].astype(jnp.float32) * w_ref[b, j]
 
-    @pl.when((j == 0) & (k == 0))
-    def _init_acc_o():
-        acc_o[...] = jnp.zeros_like(acc_o)
+        @pl.when(j == 0)
+        def _first():
+            acc_a[...] = part
 
-    b = pl.program_id(0)
-    m = mask_ref[b, j].astype(jnp.float32)
-    acc_a[...] += a_ref[...].astype(jnp.float32) * m
+        @pl.when(j > 0)
+        def _add():
+            acc_a[...] += part
 
-    @pl.when(j == d_max - 1)
+    @pl.when(j == deg - 1)
     def _matmul():
-        acc_o[...] += jax.lax.dot_general(
-            acc_a[...],
-            x_ref[...].astype(jnp.float32),
+        o_ref[...] = jax.lax.dot_general(
+            acc_a[...].astype(x_ref.dtype),
+            x_ref[...],
             (((1,), (0,)), ((), ())),
             precision=precision,
             preferred_element_type=jnp.float32,
-        )
+        ).astype(o_ref.dtype)
 
-        @pl.when(k == nk - 1)
-        def _write():
-            o_ref[...] = acc_o[...].astype(o_ref.dtype)
+    @pl.when((deg == 0) & (j == 0))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def compact(idx: jnp.ndarray, mask: jnp.ndarray):
+    """Real slots first: (deg (C,), idx (C, d_max), w (C, d_max)).
+
+    A stable argsort of ``mask == 0`` keeps the real neighbours in order;
+    padded slots repeat the block's last real neighbour with weight 0."""
+    real = mask != 0
+    deg = real.sum(axis=1).astype(jnp.int32)
+    order = jnp.argsort(~real, axis=1, stable=True)
+    idx_c = jnp.take_along_axis(idx.astype(jnp.int32), order, axis=1)
+    w_c = jnp.take_along_axis(mask.astype(jnp.float32), order, axis=1)
+    last = jnp.take_along_axis(idx_c, jnp.maximum(deg - 1, 0)[:, None], axis=1)
+    slot = jnp.arange(idx.shape[1])[None, :]
+    idx_c = jnp.where(slot < deg[:, None], idx_c, last)
+    return deg, idx_c, w_c
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "interpret", "out_dtype"),
+    static_argnames=("bm", "bn", "interpret", "out_dtype"),
 )
 def coded_matmul_pallas(
     a: jnp.ndarray,     # (R * bm, k_dim)
     x: jnp.ndarray,     # (k_dim, n_dim)
     idx: jnp.ndarray,   # (C, d_max) int32
-    mask: jnp.ndarray,  # (C, d_max) any dtype; nonzero = valid
+    mask: jnp.ndarray,  # (C, d_max) any dtype; nonzero = valid, the weight
     *,
     bm: int,
-    bk: int,
-    bn: int,
+    bn: Optional[int] = None,
     interpret: bool = False,
     out_dtype=None,
 ) -> jnp.ndarray:
@@ -86,49 +149,37 @@ def coded_matmul_pallas(
     C, d_max = idx.shape
     if a.shape[1] != k_dim:
         raise ValueError(f"a cols {a.shape[1]} != x rows {k_dim}")
-    if k_dim % bk or n_dim % bn or a.shape[0] % bm:
-        raise ValueError(
-            f"shapes (a={a.shape}, x={x.shape}) not divisible by "
-            f"blocks (bm={bm}, bk={bk}, bn={bn}); pad in ops.py"
-        )
-    nk, nn = k_dim // bk, n_dim // bn
-    out_dtype = out_dtype or x.dtype
+    if a.shape[0] % bm:
+        raise ValueError(f"a rows {a.shape[0]} not divisible by bm={bm}")
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    in_size = max(a.dtype.itemsize, x.dtype.itemsize)
+    bn = tile_n(bm, k_dim, n_dim, in_size, out_dtype.itemsize, cap=bn)
 
-    grid = (C, nn, nk, d_max)
-    # Mosaic's default contraction for f32 operands rounds them to bf16
-    # (measured on a TPU v5e: 2e-3 of the scale at k=4096); f32 inputs get
-    # an f32 product.  bf16 inputs keep the one-pass default, whose error
-    # is under that of the bf16 output.
+    # Mosaic's default contraction rounds f32 operands to bf16 (measured on
+    # a TPU v5e: 2e-3 of the scale at k=4096), so f32 inputs ask for an
+    # f32 product.  bf16 inputs keep the one-pass default, whose error is
+    # under that of the bf16 output.
     precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
                  else jax.lax.Precision.DEFAULT)
-    kernel = functools.partial(_kernel, d_max=d_max, nk=nk,
-                               precision=precision)
+    deg, idx_c, w_c = compact(idx, mask)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(n_dim // bn, C, d_max),
         in_specs=[
-            pl.BlockSpec(  # A: gather row-block idx[b, j], k-tile k
-                (bm, bk),
-                lambda b, n, k, j, idx_ref, mask_ref: (idx_ref[b, j], k),
-            ),
-            pl.BlockSpec(  # X: (k, n) tile
-                (bk, bn),
-                lambda b, n, k, j, idx_ref, mask_ref: (k, n),
-            ),
+            pl.BlockSpec(  # A: whole-K row block idx[b, j]
+                (bm, k_dim), lambda n, b, j, deg, idx, w: (idx[b, j], 0)),
+            pl.BlockSpec(  # X: the output tile's columns, all of K
+                (k_dim, bn), lambda n, b, j, deg, idx, w: (0, n)),
         ],
-        out_specs=pl.BlockSpec(
-            (bm, bn), lambda b, n, k, j, idx_ref, mask_ref: (b, n)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bk), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda n, b, j, deg, idx, w: (b, n)),
+        scratch_shapes=[pltpu.VMEM((bm, k_dim), jnp.float32)],
     )
     fn = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, precision=precision),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C * bm, n_dim), out_dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="coded_matmul",
     )
-    return fn(idx.astype(jnp.int32), mask.astype(jnp.float32), a, x)
+    return fn(deg, idx_c, w_c, a, x)

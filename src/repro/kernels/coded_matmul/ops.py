@@ -1,7 +1,8 @@
-"""Jitted public wrapper for the fused coded matmul.
+"""Public wrapper for the fused coded matmul.
 
-Handles padding to block multiples, the jnp fallback (used on CPU and in the
-dry-run lowering), and LTCode plumbing.
+Handles padding n to lane multiples, the tile choice and its counters, the
+jnp fallback (used on CPU and in the dry-run lowering), and LTCode
+plumbing.
 """
 
 from __future__ import annotations
@@ -9,11 +10,13 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...core.fountain import LTCode
-from .kernel import coded_matmul_pallas
+from ...utils import tracing
+from .kernel import LANE, coded_matmul_pallas, tile_n
 from .ref import coded_matmul_ref
 
 
@@ -21,10 +24,6 @@ def _pad_to(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-@functools.partial(
-    __import__("jax").jit,
-    static_argnames=("bm", "bk", "bn", "use_pallas", "interpret"),
-)
 def coded_matmul(
     a: jnp.ndarray,
     x: jnp.ndarray,
@@ -32,24 +31,44 @@ def coded_matmul(
     mask: jnp.ndarray,
     *,
     bm: int,
-    bk: int = 256,
-    bn: int = 256,
+    bn: Optional[int] = None,
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """V[b*bm:(b+1)*bm] = (sum_j mask[b,j] A[idx[b,j]]) @ x for coded block b.
 
     a: (R*bm, k_dim); x: (k_dim, n_dim); idx/mask: (C, d_max).
-    Returns (C*bm, n_dim).
+    Returns (C*bm, n_dim).  ``bn`` bounds the kernel's output tile width,
+    which is otherwise set by the shapes (``kernel.tile_n``).
+
+    Each trace of the Pallas path counts ``coded_matmul.grid_steps``
+    (n_tiles * C * d_max), and ``coded_matmul.real_pairs`` (nonzero mask
+    entries) where ``mask`` is a host array, as a plan's tables are.
     """
+    real_pairs = (int(np.count_nonzero(mask))
+                  if use_pallas and isinstance(mask, np.ndarray) else None)
+    return _coded_matmul(a, x, idx, mask, bm=bm, bn=bn, use_pallas=use_pallas,
+                         interpret=interpret, real_pairs=real_pairs)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("bm", "bn", "use_pallas", "interpret", "real_pairs"),
+)
+def _coded_matmul(a, x, idx, mask, *, bm, bn, use_pallas, interpret,
+                  real_pairs):
     if not use_pallas:
         return coded_matmul_ref(a, x, idx, mask, bm)
     k_dim, n_dim = x.shape
-    kp, np_ = _pad_to(k_dim, bk), _pad_to(n_dim, bn)
-    a_p = jnp.pad(a, ((0, 0), (0, kp - k_dim)))
-    x_p = jnp.pad(x, ((0, kp - k_dim), (0, np_ - n_dim)))
+    np_ = n_dim if n_dim <= LANE else _pad_to(n_dim, LANE)
+    x_p = jnp.pad(x, ((0, 0), (0, np_ - n_dim)))
+    bn = tile_n(bm, k_dim, np_, max(a.dtype.itemsize, x.dtype.itemsize),
+                x.dtype.itemsize, cap=bn)
+    tracing.count("coded_matmul.grid_steps", np_ // bn * idx.size)
+    if real_pairs is not None:
+        tracing.count("coded_matmul.real_pairs", real_pairs)
     out = coded_matmul_pallas(
-        a_p, x_p, idx, mask, bm=bm, bk=bk, bn=bn, interpret=interpret
+        a, x_p, idx, mask, bm=bm, bn=bn, interpret=interpret
     )
     return out[:, :n_dim]
 
@@ -77,7 +96,10 @@ def flops(R: int, K: int, bm: int, k_dim: int, n_dim: int, d_mean: float) -> dic
     """Roofline terms for one fused coded matmul (per §Roofline).
 
     Returns flops of the MXU matmul part, VPU encode adds, and HBM bytes
-    moved (bf16), for napkin math in benchmarks/kernel_bench.py.
+    moved (bf16), for napkin math in benchmarks/kernel_bench.py.  Fused,
+    A is read once per real (block, neighbour) pair, d_mean * C row blocks,
+    not once per C * d_max slot (padded slots issue no copy); x once and
+    the coded output once, with one output tile (``kernel.tile_n``).
     """
     C = R + K
     matmul = 2.0 * C * bm * k_dim * n_dim

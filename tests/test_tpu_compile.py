@@ -67,8 +67,10 @@ R, K, BM, D_PAR = 32, 8, 256, 16
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_coded_matmul_compiles(one_chip, dtype):
+    # whole-K row blocks; the output tile comes from the VMEM rule (bf16:
+    # all of n, f32: 512 wide)
     kdim = ndim = 4096
-    fn = functools.partial(coded_matmul_pallas, bm=BM, bk=256, bn=256)
+    fn = functools.partial(coded_matmul_pallas, bm=BM)
     _assert_kernel(
         fn,
         _spec((R * BM, kdim), dtype, one_chip),

@@ -111,6 +111,28 @@ def _peel_plan():
     return cm.plan_coded_matmul(rows=512, n_shards=8, overhead=0.25, bm=32)
 
 
+def test_coded_matmul_counts_grid_steps_and_real_pairs():
+    """One count of each per trace of the op: every (block, slot) step of
+    the grid, and the plan's real (block, neighbour) pairs."""
+    plan = _dense_plan()
+    C, d_max = plan.code.idx.shape
+    rows = plan.code.R * plan.bm
+    a = jnp.ones((rows, 24), jnp.float32)
+    x = jnp.ones((24, 40), jnp.float32)
+    before = tracing.totals()
+    for _ in range(2):
+        cm.run(plan, a, x, use_pallas=True, interpret=True)
+    after = tracing.totals()
+
+    def added(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    assert added("coded_matmul.grid_steps") == C * d_max
+    assert added("coded_matmul.real_pairs") == np.count_nonzero(
+        plan.code.weights)
+    assert 0 < np.count_nonzero(plan.code.weights) < C * d_max
+
+
 @pytest.mark.parametrize("make_plan,path,children", [
     (_dense_plan, "dense", ["recover.gather", "decode.peel_plan",
                             "decode.rank", "decode.convert", "decode.pinv",
