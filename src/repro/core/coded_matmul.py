@@ -107,53 +107,70 @@ def device_blocks(plan: CodedMatmulPlan, a: jnp.ndarray) -> Tuple[np.ndarray, np
 
 def run(
     plan: CodedMatmulPlan,
-    a: jnp.ndarray,             # (rows, k_dim) source matrix
+    a: jnp.ndarray,             # (rows, k_dim) source matrix; (E, rows, k_dim) grouped
     x: jnp.ndarray,             # (k_dim, n_dim)
     mesh: Optional[Mesh] = None,
     axis: str = "model",
     use_pallas: bool = False,
     interpret: bool = False,
+    *,
+    tile_group: Optional[jnp.ndarray] = None,
+    live_tiles: Optional[jnp.ndarray] = None,
+    bn: Optional[int] = None,
 ) -> jnp.ndarray:
     """Compute all coded block products, laid out shard-major:
     out[s*Bp+i] = (G A)[placement[s, i]] @ x, shape (S*Bp*bm, n_dim).
+
+    Grouped columns (``tile_group``, ``live_tiles``, ``bn``; see
+    ``kernels.coded_matmul.coded_matmul``): ``a`` stacks E matrices under
+    the plan's one code, and each ``bn``-column tile of x is multiplied by
+    its own matrix's coded blocks.
 
     With a mesh, the coded-row dim is sharded over ``axis`` via shard_map —
     each device encodes+computes only its own blocks (the paper's helpers).
     """
     idx, mask = device_blocks(plan, a)
+    groups = () if tile_group is None else (tile_group, live_tiles)
 
-    def local(a_full, x_full, idx_s, mask_s):
+    def local(a_full, x_full, idx_s, mask_s, *groups):
+        tiles = dict(zip(("tile_group", "live_tiles"), groups))
         return coded_matmul_op(
-            a_full, x_full, idx_s, mask_s, bm=plan.bm,
-            use_pallas=use_pallas, interpret=interpret,
+            a_full, x_full, idx_s, mask_s, bm=plan.bm, bn=bn,
+            use_pallas=use_pallas, interpret=interpret, **tiles,
         )
 
     if mesh is None:
-        return local(a, x, idx, mask)
+        return local(a, x, idx, mask, *groups)
 
     fn = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(), P(), P(axis), P(axis)),
+        in_specs=(P(), P(), P(axis), P(axis)) + (P(),) * len(groups),
         out_specs=P(axis),
         check_vma=False,
     )
-    return fn(a, x, idx, mask)
+    return fn(a, x, idx, mask, *groups)
 
 
 def recover(
     plan: CodedMatmulPlan,
     out: jnp.ndarray,           # (S*Bp*bm, n_dim) coded results
     survivors: np.ndarray,      # shard ids that returned
+    product: Optional[str] = None,
 ) -> jnp.ndarray:
     """Collector-side recovery of y = A x from surviving shards only.
+    Columns are independent, so grouped columns recover in one call.
 
-    One ``recover`` span (attrs ``n_rx``, ``path``) per call: its child
+    One ``recover`` span (attrs ``n_rx``, ``path`` and the caller's
+    ``product`` name where given) per call: its child
     ``recover.gather`` covers the survivors' slices and their concatenation
     (over a mesh, where the cross-chip gather is enqueued), and
     ``fountain.decode`` adds its own."""
     Bp, bm = plan.blocks_per_shard, plan.bm
-    with tracing.span("recover", n_rx=len(survivors) * Bp) as attrs:
+    attrs = {"n_rx": len(survivors) * Bp}
+    if product is not None:
+        attrs["product"] = product
+    with tracing.span("recover", **attrs) as attrs:
         with tracing.span("recover.gather"):
             rows = []
             ids = []

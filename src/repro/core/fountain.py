@@ -440,7 +440,7 @@ def plan_rounds(plan: DecodePlan) -> list:
     directly-received (degree-1) sources at round 0.  Steps inside one round
     are mutually independent, so a round executes as a single batched peel —
     the round count is the decode's critical path, typically O(log R) deep
-    versus the O(R) sequential scan of :func:`apply_decode_plan`.
+    versus the O(R) sequential steps of the plan.
     """
     depth = np.full(plan.R, -1, dtype=np.int64)
     depth[plan.direct_src] = 0
@@ -466,33 +466,42 @@ def plan_rounds(plan: DecodePlan) -> list:
     return rounds
 
 
-def apply_decode_plan(coded_rx: jnp.ndarray, plan: DecodePlan) -> jnp.ndarray:
-    """Apply a peeling plan to received coded blocks (n_rx, *rest) -> (R, *rest)."""
-    rest = coded_rx.shape[1:]
-    src = jnp.zeros((plan.R,) + rest, dtype=coded_rx.dtype)
-    if plan.direct_src.size:
-        dcoef = jnp.asarray(plan.direct_coef).reshape((-1,) + (1,) * len(rest))
-        src = src.at[jnp.asarray(plan.direct_src)].set(
-            coded_rx[jnp.asarray(plan.direct_coded)] / dcoef.astype(coded_rx.dtype)
-        )
-    if plan.order_src.size == 0:
-        return src
-
-    order_coded = jnp.asarray(plan.order_coded)
-    order_src = jnp.asarray(plan.order_src)
-    order_pivot = jnp.asarray(plan.order_pivot)
-    nbr_idx = jnp.asarray(plan.order_nbr_idx)
-    nbr_coef = jnp.asarray(plan.order_nbr_coef)
-
-    def step(src, t):
-        c = coded_rx[order_coded[t]]
-        gathered = src[nbr_idx[t]]  # (d_max, *rest)
-        w = nbr_coef[t].astype(src.dtype).reshape((-1,) + (1,) * len(rest))
-        val = (c - (gathered * w).sum(axis=0)) / order_pivot[t].astype(src.dtype)
-        return src.at[order_src[t]].set(val), None
-
-    src, _ = jax.lax.scan(step, src, jnp.arange(plan.order_src.shape[0]))
+def plan_matrix(plan: DecodePlan, n_rx: int) -> np.ndarray:
+    """The peeling plan as the (R, n_rx) matrix that maps the received
+    blocks to the sources: each step is linear, so replaying the plan on
+    the identity (float64, on the host) gives it exactly."""
+    eye = np.eye(n_rx)
+    src = np.zeros((plan.R, n_rx))
+    src[plan.direct_src] = eye[plan.direct_coded] / plan.direct_coef[:, None]
+    for t in range(plan.n_peeled):
+        nbr = np.einsum("j,jn->n", plan.order_nbr_coef[t],
+                        src[plan.order_nbr_idx[t]])
+        src[plan.order_src[t]] = (eye[plan.order_coded[t]] - nbr) / (
+            plan.order_pivot[t])
     return src
+
+
+def apply_decode_plan(coded_rx: jnp.ndarray, plan: DecodePlan) -> jnp.ndarray:
+    """Apply a peeling plan to received coded blocks (n_rx, *rest) -> (R, *rest).
+
+    The plan's matrix (``plan_matrix``) is applied on the device in one
+    matmul accumulated in float32: one compiled program per payload shape,
+    whatever the plan, and no sequential scan over the peeled blocks.
+    With the codes' +-1 coefficients the matrix is small integers, exact
+    in the payload's dtype, so it multiplies the payload as it is (no
+    float32 copy: at the coded expert layer's sizes that copy and its
+    three-way split took gigabytes); otherwise the payload is converted
+    and multiplied at full float32 precision, as the dense solve does."""
+    n_rx = coded_rx.shape[0]
+    m = plan_matrix(plan, n_rx)
+    flat = coded_rx.reshape(n_rx, -1)
+    m_c = m.astype(flat.dtype)
+    if not np.array_equal(m_c.astype(np.float64), m):
+        m_c, flat = m.astype(np.float32), flat.astype(jnp.float32)
+    sol = jnp.matmul(jnp.asarray(m_c), flat,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return sol.reshape((plan.R,) + coded_rx.shape[1:]).astype(coded_rx.dtype)
 
 
 def decode(
@@ -512,6 +521,7 @@ def decode(
     default f32 matmul rounds its inputs to bf16).
 
     Spans (``repro.utils.tracing``): ``decode.peel_plan``, then on the
+    peel path ``decode.apply`` (the plan's matrix and its matmul), on the
     dense path ``decode.rank``, ``decode.convert`` (the payload's f32
     conversion), ``decode.pinv`` (host pinv and its upload) and
     ``decode.solve`` (the device matmul and the reshapes); counters
@@ -522,7 +532,8 @@ def decode(
         plan = peel_decode_plan(code, received_ids)
     if plan is not None:
         tracing.count("decode.peel")
-        return apply_decode_plan(coded_rx, plan), "peel"
+        with tracing.span("decode.apply"):
+            return apply_decode_plan(coded_rx, plan), "peel"
     with tracing.span("decode.rank"):
         G = code.dense_generator()[np.asarray(received_ids)]  # (n_rx, R)
         if np.linalg.matrix_rank(G) < code.R:

@@ -17,7 +17,7 @@ import numpy as np
 from ...core.fountain import LTCode
 from ...utils import tracing
 from .kernel import LANE, coded_matmul_pallas, tile_n
-from .ref import coded_matmul_ref
+from .ref import coded_matmul_grouped_ref, coded_matmul_ref
 
 
 def _pad_to(v: int, m: int) -> int:
@@ -32,6 +32,8 @@ def coded_matmul(
     *,
     bm: int,
     bn: Optional[int] = None,
+    tile_group: Optional[jnp.ndarray] = None,
+    live_tiles: Optional[jnp.ndarray] = None,
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -41,35 +43,48 @@ def coded_matmul(
     Returns (C*bm, n_dim).  ``bn`` bounds the kernel's output tile width,
     which is otherwise set by the shapes (``kernel.tile_n``).
 
+    Grouped columns: ``a`` is (E, R*bm, k_dim), n_dim is whole ``bn``-wide
+    tiles, ``tile_group`` (n_dim // bn,) names each tile's matrix and
+    ``live_tiles`` (1,) counts the tiles that hold columns; the rest come
+    out zero.
+
     Each trace of the Pallas path counts ``coded_matmul.grid_steps``
     (n_tiles * C * d_max), and ``coded_matmul.real_pairs`` (nonzero mask
     entries) where ``mask`` is a host array, as a plan's tables are.
     """
     real_pairs = (int(np.count_nonzero(mask))
                   if use_pallas and isinstance(mask, np.ndarray) else None)
-    return _coded_matmul(a, x, idx, mask, bm=bm, bn=bn, use_pallas=use_pallas,
-                         interpret=interpret, real_pairs=real_pairs)
+    if tile_group is not None and (bn is None or x.shape[1] % bn):
+        raise ValueError(f"grouped columns need whole tiles: n={x.shape[1]}, "
+                         f"bn={bn}")
+    return _coded_matmul(a, x, idx, mask, tile_group, live_tiles, bm=bm,
+                         bn=bn, use_pallas=use_pallas, interpret=interpret,
+                         real_pairs=real_pairs)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("bm", "bn", "use_pallas", "interpret", "real_pairs"),
 )
-def _coded_matmul(a, x, idx, mask, *, bm, bn, use_pallas, interpret,
-                  real_pairs):
+def _coded_matmul(a, x, idx, mask, tile_group, live_tiles, *, bm, bn,
+                  use_pallas, interpret, real_pairs):
     if not use_pallas:
-        return coded_matmul_ref(a, x, idx, mask, bm)
+        if tile_group is None:
+            return coded_matmul_ref(a, x, idx, mask, bm)
+        return coded_matmul_grouped_ref(a, x, idx, mask, bm, tile_group,
+                                        live_tiles, bn)
     k_dim, n_dim = x.shape
-    np_ = n_dim if n_dim <= LANE else _pad_to(n_dim, LANE)
-    x_p = jnp.pad(x, ((0, 0), (0, np_ - n_dim)))
-    bn = tile_n(bm, k_dim, np_, max(a.dtype.itemsize, x.dtype.itemsize),
-                x.dtype.itemsize, cap=bn)
-    tracing.count("coded_matmul.grid_steps", np_ // bn * idx.size)
+    if tile_group is None:
+        # Lane-aligned n, and the widest tile the VMEM budget allows.
+        np_ = n_dim if n_dim <= LANE else _pad_to(n_dim, LANE)
+        x = jnp.pad(x, ((0, 0), (0, np_ - n_dim)))
+        bn = tile_n(bm, k_dim, np_, max(a.dtype.itemsize, x.dtype.itemsize),
+                    x.dtype.itemsize, cap=bn)
+    tracing.count("coded_matmul.grid_steps", x.shape[1] // bn * idx.size)
     if real_pairs is not None:
         tracing.count("coded_matmul.real_pairs", real_pairs)
-    out = coded_matmul_pallas(
-        a, x_p, idx, mask, bm=bm, bn=bn, interpret=interpret
-    )
+    out = coded_matmul_pallas(a, x, idx, mask, tile_group, live_tiles, bm=bm,
+                              bn=bn, interpret=interpret)
     return out[:, :n_dim]
 
 

@@ -36,6 +36,27 @@ def coded_matmul_ref(
     return out.reshape(-1, x.shape[1]).astype(x.dtype)
 
 
+def coded_matmul_grouped_ref(
+    a: jnp.ndarray,           # (E, R * bm, k_dim)
+    x: jnp.ndarray,           # (k_dim, n_dim), n_dim = n_tiles * bn
+    idx: jnp.ndarray,
+    mask: jnp.ndarray,
+    bm: int,
+    tile_group: jnp.ndarray,  # (n_tiles,) matrix of each column tile
+    live_tiles: jnp.ndarray,  # (1,) tiles that hold columns
+    bn: int,
+) -> jnp.ndarray:
+    """Grouped columns: column c is multiplied by the coded blocks of
+    ``a[tile_group[c // bn]]``; columns of dead tiles come out zero."""
+    col = jnp.arange(x.shape[1]) // bn
+    group = jnp.where(col < live_tiles[0], tile_group[col], -1)
+    out = jnp.zeros((idx.shape[0] * bm, x.shape[1]), jnp.float32)
+    for e in range(a.shape[0]):
+        x_e = jnp.where(group == e, x, jnp.zeros_like(x))
+        out += coded_matmul_ref(a[e], x_e, idx, mask, bm).astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
 def lt_encode_ref(
     a: jnp.ndarray,      # (R * bm, n_cols)
     idx: jnp.ndarray,    # (C, d_max)

@@ -1,8 +1,9 @@
 """Pure-jnp reference for the round-based LT payload decode.
 
-The offline :func:`repro.core.fountain.apply_decode_plan` walks the peeling
-schedule one source at a time (an O(T)-step ``lax.scan``).  The kernel path
-instead executes the :func:`repro.core.fountain.plan_rounds` levelization:
+The offline :func:`repro.core.fountain.apply_decode_plan` applies the
+peeling schedule as one (R, n_rx) matrix formed on the host.  The kernel
+path instead executes the :func:`repro.core.fountain.plan_rounds`
+levelization on the device:
 every source of a round is recovered by one batched masked gather +
 subtract, so the device-side critical path is the dependency depth
 (typically O(log R)) rather than T.  This module is the jnp oracle the

@@ -3,7 +3,8 @@ token dispatch.
 
 Dispatch strategy (static shapes, EP-shardable, no (S, E, C) one-hot blowup):
 
-  1. router scores -> top_k expert ids + weights per token;
+  1. router scores -> top_k expert ids + weights per token (``route``:
+     softmax, or DeepSeek-V3's sigmoid with a selection-only bias);
   2. flatten the S*k assignments, sort by expert id;
   3. each expert e gets a static (C,) slot table: slot (e, c) holds the c-th
      token assigned to e (or -1 beyond its count — capacity drop, standard
@@ -19,7 +20,7 @@ for coded gradient aggregation stay *within* expert shards (DESIGN.md §4).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,34 @@ def init_moe(pb: ParamBuilder, cfg: ModelConfig) -> Dict[str, Any]:
     return p
 
 
+def route(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
+          bias: Optional[jnp.ndarray] = None, scale: float = 1.0):
+    """Top-k routing of float32 router logits (S, E).
+
+    Scores are the softmax over experts, or (``sigmoid``, DeepSeek-V3) each
+    expert's sigmoid.  ``bias`` (E,) is added to the scores to choose the
+    experts only (``noaux_tc``); a chosen expert's weight is its unbiased
+    score, normalised over the k chosen, times ``scale``
+    (``routed_scaling_factor``).  Returns (weights (S, k), ids (S, k),
+    scores (S, E))."""
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if bias is None:
+        top_w, top_e = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_e = jax.lax.top_k(scores + bias, top_k)
+        # The chosen scores, selected by a one-hot sum: a TPU compiles this
+        # gather with batch dimensions in seconds.
+        top_w = jnp.where(top_e[..., None] == jnp.arange(scores.shape[-1]),
+                          scores[..., None, :], 0.0).sum(-1)
+    top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)
+    return top_w * scale, top_e, scores
+
+
 def _capacity(s_tokens: int, m) -> int:
     c = int(s_tokens * m.top_k * m.capacity_factor / m.n_experts) + 1
     return max(c, m.top_k)
@@ -87,9 +116,7 @@ def moe_block(
     logits = (xf @ params["router"].astype(x.dtype)).astype(jnp.float32)  # (S, E)
     if m.router_softcap:
         logits = m.router_softcap * jnp.tanh(logits / m.router_softcap)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, m.top_k)                # (S, k)
-    top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)
+    top_w, top_e, probs = route(logits, m.top_k)                 # (S, k)
 
     # ---- sort-based dispatch -------------------------------------------
     C = _capacity(S, m)

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from .common import ACT
 from .config import ModelConfig
+from .moe import route
 
 
 def moe_block_a2a(
@@ -52,9 +53,7 @@ def moe_block_a2a(
         logits = (xf @ router.astype(x_loc.dtype)).astype(jnp.float32)
         if m.router_softcap:
             logits = m.router_softcap * jnp.tanh(logits / m.router_softcap)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_e = jax.lax.top_k(probs, m.top_k)
-        top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)
+        top_w, top_e, probs = route(logits, m.top_k)
 
         # FSDP re-assembly of this model-shard's experts (tiled over data)
         if data_n > 1:

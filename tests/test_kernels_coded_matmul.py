@@ -7,7 +7,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import fountain
-from repro.kernels.coded_matmul import coded_matmul, coded_matmul_code, coded_matmul_ref
+from repro.kernels.coded_matmul import (
+    coded_matmul, coded_matmul_code, coded_matmul_pallas, coded_matmul_ref)
 from repro.kernels.coded_matmul.kernel import (
     VMEM_BUDGET, VMEM_LIMIT, compact, tile_n, vmem_bytes)
 from repro.kernels.coded_matmul.ref import lt_encode_ref
@@ -88,6 +89,73 @@ def test_coded_matmul_tables(table, dtype):
     empty = np.flatnonzero(~np.asarray(w != 0).any(axis=1))
     for b in empty:
         assert not np.asarray(out[b * bm:(b + 1) * bm], np.float32).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "R,K,bm,kdim,ndim,bn",
+    [
+        (4, 2, 8, 16, 16, None),
+        (6, 3, 16, 64, 32, 16),
+        (8, 4, 8, 128, 256, 128),
+        (10, 5, 8, 32, 384, 128),
+    ],
+)
+def test_one_group_is_the_plain_kernel_bit_for_bit(R, K, bm, kdim, ndim, bn,
+                                                    dtype):
+    """E = 1 with every tile live in group 0: the plain call's grid, tile
+    and result."""
+    code = fountain.make_lt_code(R=R, K=K, seed=R * 31 + K)
+    a = _mk(jax.random.PRNGKey(0), (R * bm, kdim), dtype)
+    x = _mk(jax.random.PRNGKey(1), (kdim, ndim), dtype)
+    idx, mask = jnp.asarray(code.idx), jnp.asarray(code.mask)
+    size = jnp.dtype(dtype).itemsize
+    tile = tile_n(bm, kdim, ndim, size, size, cap=bn)
+    plain = coded_matmul_pallas(a, x, idx, mask, bm=bm, bn=bn, interpret=True)
+    tiles = ndim // tile
+    grouped = coded_matmul_pallas(
+        a[None], x, idx, mask, jnp.zeros((tiles,), jnp.int32),
+        jnp.full((1,), tiles, jnp.int32), bm=bm, bn=tile, interpret=True)
+    np.testing.assert_array_equal(np.asarray(grouped, np.float32),
+                                  np.asarray(plain, np.float32))
+
+
+# Column tiles of grouped products: each tile's matrix, and the live count
+# (the tiles past it are dead).
+GROUPS = {
+    "mixed_sizes": ([0, 0, 2, 1, 1, 1], 6),
+    "dead_tail": ([1, 2, 2, 0, 2, 2, 2, 2], 4),
+    "one_group": ([1, 1, 1], 3),
+    "all_dead": ([0, 0], 0),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["pallas", "fallback"])
+@pytest.mark.parametrize("layout", sorted(GROUPS))
+def test_grouped_columns(layout, use_pallas, dtype):
+    groups, live = GROUPS[layout]
+    E, R, K, bm, kdim, bn = 3, 6, 3, 16, 48, 128
+    code = fountain.make_lt_code(R=R, K=K, seed=5)
+    idx, w = jnp.asarray(code.idx), jnp.asarray(code.weights)
+    a = _mk(jax.random.PRNGKey(12), (E, R * bm, kdim), dtype)
+    x = _mk(jax.random.PRNGKey(13), (kdim, len(groups) * bn), dtype)
+    out = coded_matmul(a, x, idx, w, bm=bm, bn=bn,
+                       tile_group=jnp.asarray(groups, jnp.int32),
+                       live_tiles=jnp.asarray([live], jnp.int32),
+                       use_pallas=use_pallas, interpret=True)
+    assert out.shape == ((R + K) * bm, len(groups) * bn)
+    for t, g in enumerate(groups):
+        cols = slice(t * bn, (t + 1) * bn)
+        got = np.asarray(out[:, cols], np.float32)
+        if t >= live:
+            assert not got.any()
+            continue
+        ref = coded_matmul_ref(a[g], x[:, cols], idx, w.astype(jnp.float32),
+                               bm)
+        np.testing.assert_allclose(got, np.asarray(ref, np.float32),
+                                   rtol=TOL[dtype], atol=TOL[dtype] * 8)
 
 
 def test_compact_puts_real_slots_first_and_repeats_the_last():

@@ -80,6 +80,33 @@ def test_coded_matmul_compiles(one_chip, dtype):
     )
 
 
+# The coded expert layer of Moonlight-16B-A3B (hidden 2048, expert width
+# 1408), 8 held and 2 shared experts under one code per product, 1024-wide
+# column tiles over a 39,936-column buffer (39 tiles).
+MOE_PRODUCTS = {
+    # (experts, rows, k, bm, coded blocks, d_max)
+    "gate_up": (10, 2 * 1408, 2048, 256, 16, 6),
+    "down": (10, 2048, 1408, 128, 24, 8),
+}
+
+
+@pytest.mark.parametrize("product", sorted(MOE_PRODUCTS))
+def test_grouped_coded_matmul_compiles_at_moonlight_widths(one_chip,
+                                                          product):
+    E, rows, kdim, bm, C, d_max = MOE_PRODUCTS[product]
+    bn, tiles = 1024, 39
+    fn = functools.partial(coded_matmul_pallas, bm=bm, bn=bn)
+    _assert_kernel(
+        fn,
+        _spec((E, rows, kdim), jnp.bfloat16, one_chip),
+        _spec((kdim, tiles * bn), jnp.bfloat16, one_chip),
+        _spec((C, d_max), jnp.int32, one_chip),
+        _spec((C, d_max), jnp.float32, one_chip),
+        _spec((tiles,), jnp.int32, one_chip),
+        _spec((1,), jnp.int32, one_chip),
+    )
+
+
 def test_lt_encode_compiles(one_chip):
     cols, d_max = 4096, 16
     fn = functools.partial(lt_encode_pallas, bm=BM, bc=512)

@@ -137,7 +137,8 @@ def test_coded_matmul_counts_grid_steps_and_real_pairs():
     (_dense_plan, "dense", ["recover.gather", "decode.peel_plan",
                             "decode.rank", "decode.convert", "decode.pinv",
                             "decode.solve"]),
-    (_peel_plan, "peel", ["recover.gather", "decode.peel_plan"]),
+    (_peel_plan, "peel", ["recover.gather", "decode.peel_plan",
+                          "decode.apply"]),
 ])
 def test_recover_spans_and_counter(make_plan, path, children):
     plan = make_plan()
@@ -227,3 +228,48 @@ def test_batch_program_carries_the_simulator_scopes(fleet):
     # The completion sort and the pacing scan's loop sit under their scopes.
     assert any("completion" in n and "sort" in n for n in names)
     assert any("policy_stream" in n and n.endswith("while") for n in names)
+
+
+def test_coded_expert_layer_scopes_spans_and_counters():
+    """The layer's programs carry the ``moe.*`` scopes in their compiled
+    op_name metadata; each request's two ``recover`` spans name their
+    product; the kernel's counters count once per trace of a program."""
+    from repro.core import coded_moe as CM
+
+    d, f, E = 128, 64, 16
+    share = CM.ExpertShare(d_model=d, d_expert=f, n_experts=E, top_k=4,
+                           n_shared=2, share=0, n_shares=4,
+                           scoring="sigmoid", scale=2.446)
+    layer = CM.CodedExperts(share, n_shards=4, overhead=0.5, bm_gate_up=16,
+                            bm_down=16, use_pallas=True,
+                            interpret=True)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    w_gu, w_down = CM.pack(
+        jax.random.normal(ks[0], (4, f, d)), jax.random.normal(ks[1], (4, f, d)),
+        jax.random.normal(ks[2], (4, d, f)),
+        jax.random.normal(ks[3], (2 * f, d)),
+        jax.random.normal(ks[4], (2 * f, d)),
+        jax.random.normal(ks[5], (d, 2 * f)))
+    params = dict(router=jax.random.normal(ks[0], (E, d)),
+                  bias=jnp.zeros((E,)), w_gu=w_gu, w_down=w_down)
+    x = jax.random.normal(ks[1], (d, 64))
+    before = tracing.totals().get("coded_matmul.grid_steps", 0)
+    layer(params, x, 1)
+    first = tracing.totals().get("coded_matmul.grid_steps", 0) - before
+    cols = layer(params, x, 2)[3]
+    t0 = time.perf_counter()
+    layer(params, x, 3)
+    again = tracing.totals().get("coded_matmul.grid_steps", 0) - before
+    tiles = cols.tile_group.shape[0]
+    assert first == again == tiles * sum(
+        p.code.idx.size for p in (layer.plan_gu, layer.plan_dn))
+    recs = _spans(tracing.records(t0))
+    recovers = [r for r in recs if r.name == "recover"]
+    assert [r.attrs["product"] for r in recovers] == ["gate_up", "down"]
+    assert [r.name for r in recs if r.name == "moe.size"] == ["moe.size"]
+    found = set()
+    for texts in layer.programs().values():
+        for text in texts:
+            for name in _OP_NAME.findall(text):
+                found.update(p for p in name.split("/") if p.startswith("moe."))
+    assert found == {"moe.route", "moe.swiglu", "moe.combine"}
