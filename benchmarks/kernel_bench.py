@@ -61,7 +61,7 @@ def run() -> dict:
     # Structural accounting per plan: the kernel executes one pallas_call
     # per dependency level (fountain.plan_rounds), so the device-side
     # critical path is the level count, not the O(R) sequential step count
-    # of apply_decode_plan.  Pure VPU + DMA — memory bound by design.
+    # of the peeling plan.  Pure VPU + DMA — memory bound by design.
     from repro.core import decode as decode_mod
     from repro.kernels.lt_decode import lt_decode
 

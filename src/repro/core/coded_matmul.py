@@ -34,6 +34,10 @@ class CodedMatmulPlan:
     n_shards: int
     placement: np.ndarray      # (n_shards, blocks_per_shard) coded ids
     bm: int                    # rows per block
+    # (survivors, placement) -> the survivor set's decoder on the device
+    # (``recover``)
+    decoders: dict = dataclasses.field(default_factory=dict, compare=False,
+                                       repr=False)
 
     @property
     def blocks_per_shard(self) -> int:
@@ -152,33 +156,55 @@ def run(
     return fn(a, x, idx, mask, *groups)
 
 
+def _decoder(plan: CodedMatmulPlan, out: jnp.ndarray, survivors):
+    """(decoder, path) of ``survivors`` for payloads placed like ``out``:
+    built on the first call for each (survivor set, placement) and kept on
+    the plan, on ``out``'s device or replicated over its mesh."""
+    s = out.sharding
+    where = NamedSharding(s.mesh, P()) if isinstance(s, NamedSharding) else s
+    key = (tuple(int(v) for v in survivors), where)
+    hit = plan.decoders.get(key)
+    if hit is None:
+        tracing.count("recover.matrix_build")
+        surv, Bp = np.asarray(survivors), plan.blocks_per_shard
+        m, path = fountain.decode_matrix(
+            plan.code, plan.placement[surv].reshape(-1))
+        blocks = (surv[:, None] * Bp + np.arange(Bp)).reshape(-1)
+        dec = jax.device_put(fountain.decoder(m, blocks, path), where)
+        hit = plan.decoders[key] = (dec, path)
+    return hit
+
+
 def recover(
     plan: CodedMatmulPlan,
     out: jnp.ndarray,           # (S*Bp*bm, n_dim) coded results
     survivors: np.ndarray,      # shard ids that returned
     product: Optional[str] = None,
 ) -> jnp.ndarray:
-    """Collector-side recovery of y = A x from surviving shards only.
-    Columns are independent, so grouped columns recover in one call.
+    """Collector-side recovery of y = A x from surviving shards only, as
+    one program over ``out`` as ``run`` returns it
+    (``fountain.apply_decoder``): each source is summed from the
+    survivors' blocks by the survivor set's decode matrix.  Columns are
+    independent, so grouped columns recover in one call.  Over a mesh the
+    blocks are gathered inside the program, and y is replicated.
 
     One ``recover`` span (attrs ``n_rx``, ``path`` and the caller's
-    ``product`` name where given) per call: its child
-    ``recover.gather`` covers the survivors' slices and their concatenation
-    (over a mesh, where the cross-chip gather is enqueued), and
-    ``fountain.decode`` adds its own."""
-    Bp, bm = plan.blocks_per_shard, plan.bm
-    attrs = {"n_rx": len(survivors) * Bp}
+    ``product`` name where given) per call.  The first call for a survivor
+    set builds its matrix (counter ``recover.matrix_build``, spans of
+    ``fountain.decode_matrix``); every call enqueues the program under
+    ``decode.apply`` (peel) or ``decode.solve`` (dense) and counts
+    ``decode.peel`` or ``decode.dense``."""
+    attrs = {"n_rx": len(survivors) * plan.blocks_per_shard}
     if product is not None:
         attrs["product"] = product
     with tracing.span("recover", **attrs) as attrs:
-        with tracing.span("recover.gather"):
-            rows = []
-            ids = []
-            for s in survivors:
-                sl = out[s * Bp * bm : (s + 1) * Bp * bm]
-                rows.append(sl.reshape(Bp, bm, -1))
-                ids.extend(plan.placement[s].tolist())
-            coded_rx = jnp.concatenate(rows, axis=0)  # (n_rx, bm, n_dim)
-        dec, attrs["path"] = fountain.decode(coded_rx, plan.code,
-                                             np.asarray(ids))
-        return dec.reshape(plan.code.R * bm, -1)
+        dec, path = _decoder(plan, out, survivors)
+        attrs["path"] = path
+        s = out.sharding
+        split = isinstance(s, NamedSharding) and s.spec and s.spec[0]
+        with tracing.span("decode.apply" if path == "peel"
+                          else "decode.solve"):
+            y = fountain.apply_decoder(dec, out, bm=plan.bm,
+                                       sharding=s if split else None)
+        tracing.count(f"decode.{path}")
+        return y

@@ -3,6 +3,9 @@
 - coded_matmul: fused LT-encode + block matmul — the paper's own hot spot
   (helpers computing fountain-coded sub-matrix products) adapted to the MXU.
 - lt_encode: standalone gather-accumulate encoder (coded gradient parities).
+- block_combine: the collector's recovery, each source block summed on the
+  VPU from the received blocks its decode matrix names
+  (``core.fountain.apply_decoder``).
 - flash_attention: tiled online-softmax attention (causal / sliding-window /
   logit-softcap / GQA) — the serving & training hot spot of the assigned
   architectures.

@@ -1,6 +1,6 @@
 """Pure-jnp reference for the round-based LT payload decode.
 
-The offline :func:`repro.core.fountain.apply_decode_plan` applies the
+The offline :func:`repro.core.fountain.apply_decoder` applies the
 peeling schedule as one (R, n_rx) matrix formed on the host.  The kernel
 path instead executes the :func:`repro.core.fountain.plan_rounds`
 levelization on the device:
@@ -37,7 +37,7 @@ def lt_decode_ref(coded_rx: jnp.ndarray, plan: fountain.DecodePlan,
 
     Bit-compatible with the Pallas kernel path (same round schedule, same
     accumulation order) and numerically equal to
-    :func:`fountain.apply_decode_plan` up to fp addition order.
+    :func:`fountain.apply_decoder` up to fp addition order.
     """
     n_cols = coded_rx.shape[1]
     n_rx = coded_rx.shape[0] // bm

@@ -218,8 +218,9 @@ def test_two_routings_compile_once():
     assert cols1.tok.shape == cols2.tok.shape
     ids1 = layer(params, x1, 1)[1]
     assert not np.array_equal(np.asarray(ids1), np.asarray(ids2))
-    # The lost shard is an argument, not a new program; recovering for a
-    # new survivor set compiles only its own slices, not the programs.
+    # The lost shard is an argument, not a new program, and a new survivor
+    # set's recoveries run the programs the first one compiled.
+    assert clock.n == 0
     assert {name for name, *_ in layer._compiled} == {
         "moe_route", "moe_gate_up", "moe_down", "moe_combine"}
     assert len(layer._compiled) == 4

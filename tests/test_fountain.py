@@ -112,3 +112,88 @@ def test_failure_prob_small_for_modest_loss():
     # unrecoverability must be rare
     assert p["unrecoverable"] <= 0.05
     assert p["peel_stall"] <= 0.5
+
+
+def _peel_case():
+    code = fountain.make_lt_code(R=20, K=8, seed=5)
+    return code, np.setdiff1d(np.arange(28), [3, 9])
+
+
+def _dense_case():
+    # Losing coded blocks 0, 2 and 11 stalls peeling; the rest keep full
+    # rank, so the dense solve recovers.
+    code = fountain.make_lt_code(R=12, K=6, seed=0, parity_degree=6)
+    return code, np.setdiff1d(np.arange(18), [0, 2, 11])
+
+
+CASES = {"peel": _peel_case, "dense": _dense_case}
+
+
+@pytest.mark.parametrize("path", ["peel", "dense"])
+def test_decode_matrix_left_inverts_the_received_rows(path):
+    """The matrix maps the received rows of G back to the identity; the
+    peel matrix is the plan's, in small integers."""
+    code, keep = CASES[path]()
+    m, got = fountain.decode_matrix(code, keep)
+    assert got == path
+    G = code.dense_generator()[keep].astype(np.float64)
+    np.testing.assert_allclose(m @ G, np.eye(code.R), atol=1e-9)
+    if path == "peel":
+        plan = fountain.peel_decode_plan(code, keep)
+        np.testing.assert_array_equal(m, fountain.plan_matrix(plan,
+                                                              len(keep)))
+        np.testing.assert_array_equal(m, np.round(m))
+
+
+@pytest.mark.parametrize("path", ["peel", "dense"])
+def test_decoder_holds_the_matrix_nonzeros(path):
+    """A peel matrix's decoder holds each row's nonzeros as terms naming
+    the payload positions of the received blocks (the padding names one
+    of them); a pseudo-inverse's holds the whole matrix and those
+    positions."""
+    code, keep = CASES[path]()
+    m, _ = fountain.decode_matrix(code, keep)
+    blocks = 2 * np.arange(len(keep)) + 1      # received blocks' positions
+    dec = fountain.decoder(m, blocks, path)
+    if path == "dense":
+        assert isinstance(dec, fountain.DenseDecoder)
+        assert dec.m.dtype == np.float32 and dec.rx.dtype == np.int32
+        np.testing.assert_array_equal(dec.rx, blocks)
+        np.testing.assert_allclose(dec.m, m, rtol=2 ** -23, atol=0)
+        return
+    assert isinstance(dec, fountain.SparseDecoder)
+    assert dec.coef.dtype == np.float32 and dec.blk.shape == m.shape
+    back = np.zeros((code.R, 2 * len(keep) + 1))
+    for r in range(code.R):
+        t = dec.nnz[r]
+        back[r, dec.blk[r, :t]] += dec.coef[r, :t]
+        assert not dec.coef[r, t:].any()
+    np.testing.assert_allclose(back[:, blocks], m, rtol=2 ** -23, atol=0)
+    assert not np.delete(back, blocks, axis=1).any()
+    assert np.isin(dec.blk, blocks).all()
+    assert (dec.nnz == np.count_nonzero(m, axis=1)).all()
+
+
+@pytest.mark.parametrize("path", ["peel", "dense"])
+def test_decode_against_float64_reference(path):
+    code, keep = CASES[path]()
+    x = np.random.default_rng(0).standard_normal((code.R, 5, 3))
+    coded = np.einsum("cr,rbn->cbn", code.dense_generator().astype(np.float64),
+                      x)
+    dec, got = fountain.decode(jnp.asarray(coded[keep], jnp.float32), code,
+                               keep)
+    assert got == path
+    assert dec.shape == x.shape and dec.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(dec, np.float64), x, atol=1e-4)
+
+
+def test_decode_compiles_one_program_per_payload_shape():
+    code = fountain.make_lt_code(R=20, K=8, seed=5)
+    x = jax.random.normal(jax.random.PRNGKey(3), (20, 7, 5))
+    coded = fountain.encode(x, code)
+    before = fountain.apply_decoder._cache_size()
+    for lost in ([0, 1], [2, 20], [5, 27]):
+        keep = np.setdiff1d(np.arange(28), lost)
+        dec, _ = fountain.decode(coded[keep], code, keep)
+        np.testing.assert_allclose(np.asarray(dec), np.asarray(x), atol=1e-4)
+    assert fountain.apply_decoder._cache_size() - before == 1

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.core import coded_matmul
+from repro.core import coded_matmul, fountain
 from repro.kernels.coded_matmul import coded_matmul_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.lt_decode import lt_decode_round_pallas
@@ -160,3 +160,64 @@ def test_sharded_coded_matmul_compiles_on_four_chips(topo):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.output_shardings.spec == P("model")
+
+
+def _decoder_spec(R, width, sharding, dense):
+    if dense:
+        return fountain.DenseDecoder(_spec((width,), jnp.int32, sharding),
+                                     _spec((R, width), jnp.float32, sharding))
+    return fountain.SparseDecoder(_spec((R,), jnp.int32, sharding),
+                                  _spec((R, width), jnp.int32, sharding),
+                                  _spec((R, width), jnp.float32, sharding))
+
+
+# (coded blocks, rows a block, columns, sources, received blocks, dense):
+# the collector's recovery in the phi4 FFN cell (a pseudo-inverse) and the
+# Moonlight expert layer's two products (peel matrices).
+RECOVERIES = {
+    "phi4_ffn": (80, 256, 2048, 64, 70, True),
+    "moonlight_gate_up": (16, 256, 39 * 1024, 11, 14, False),
+    "moonlight_down": (24, 128, 39 * 1024, 16, 21, False),
+}
+
+
+def _assert_recovery(text, dense):
+    """A sparse decoder compiles to the block-combine kernel over the coded
+    output as it is (no copy, transpose or gather of the payload); a dense
+    one to the gather of the received blocks and one contraction."""
+    if dense:
+        assert "tpu_custom_call" not in text
+        assert text.count(" convolution(") + text.count(" dot(") == 1
+        assert " gather(" in text
+    else:
+        assert "tpu_custom_call" in text
+        for op in (" copy(", " transpose(", " gather(", " convolution("):
+            assert op not in text, op
+
+
+@pytest.mark.parametrize("case", sorted(RECOVERIES))
+def test_recovery_program_compiles_at_the_cells_shapes(one_chip, case):
+    """One program over the coded output viewed as blocks with no copy:
+    the kernel for a peel matrix, the contraction for a dense one."""
+    nb, bm, n, R_, width, dense = RECOVERIES[case]
+    compiled = fountain.apply_decoder.lower(
+        _decoder_spec(R_, width, one_chip, dense),
+        _spec((nb * bm, n), jnp.bfloat16, one_chip), bm=bm).compile()
+    _assert_recovery(compiled.as_text(), dense)
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "sparse"])
+def test_recovery_program_compiles_on_four_chips(topo, dense):
+    """Coded blocks split over the 'model' axis (the phi4 lost-chip cell's
+    sizes): one all-gather, the sums on every chip, a replicated result."""
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
+                             ("data", "model"))
+    split = NamedSharding(mesh, P("model"))
+    compiled = fountain.apply_decoder.lower(
+        _decoder_spec(64, 72, NamedSharding(mesh, P()), dense),
+        _spec((96 * BM, 2048), jnp.bfloat16, split), bm=BM,
+        sharding=split).compile()
+    text = compiled.as_text()
+    assert text.count(" all-gather(") == 1
+    _assert_recovery(text, dense)
+    assert compiled.output_shardings.spec == P()

@@ -133,21 +133,26 @@ def test_coded_matmul_counts_grid_steps_and_real_pairs():
     assert 0 < np.count_nonzero(plan.code.weights) < C * d_max
 
 
-@pytest.mark.parametrize("make_plan,path,children", [
-    (_dense_plan, "dense", ["recover.gather", "decode.peel_plan",
-                            "decode.rank", "decode.convert", "decode.pinv",
-                            "decode.solve"]),
-    (_peel_plan, "peel", ["recover.gather", "decode.peel_plan",
-                          "decode.apply"]),
-])
-def test_recover_spans_and_counter(make_plan, path, children):
-    plan = make_plan()
-    rows = plan.code.R * plan.bm
+PLAN_SPANS = {"dense": ["decode.peel_plan", "decode.rank", "decode.pinv"],
+              "peel": ["decode.peel_plan"]}
+PROGRAM_SPAN = {"dense": "decode.solve", "peel": "decode.apply"}
+
+
+@pytest.mark.parametrize("cached", [False, True], ids=["miss", "hit"])
+@pytest.mark.parametrize("make_plan,path", [(_dense_plan, "dense"),
+                                            (_peel_plan, "peel")])
+def test_recover_spans_and_counter(make_plan, path, cached):
+    """A recovery's first call for a survivor set builds the matrix (the
+    plan spans and ``recover.matrix_build``) and runs the program; a later
+    one runs the program only."""
+    warm = make_plan()
+    rows = warm.code.R * warm.bm
     a = jax.random.normal(jax.random.PRNGKey(0), (rows, 16), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 4), jnp.float32)
-    out = cm.run(plan, a, x)
-    surv = np.arange(1, plan.n_shards)
-    cm.recover(plan, out, surv)                  # compile outside the look
+    out = cm.run(warm, a, x)
+    surv = np.arange(1, warm.n_shards)
+    cm.recover(warm, out, surv)                  # compile outside the look
+    plan = warm if cached else make_plan()
     t0 = time.perf_counter()
     totals = tracing.totals()
     y = cm.recover(plan, out, surv)
@@ -160,6 +165,7 @@ def test_recover_spans_and_counter(make_plan, path, children):
     assert root.parent is None and root.root == root.id
     assert root.attrs == {"n_rx": 7 * plan.blocks_per_shard, "path": path}
     kids = [r for r in _spans(recs) if r.id != root.id]
+    children = ([] if cached else PLAN_SPANS[path]) + [PROGRAM_SPAN[path]]
     assert [k.name for k in kids] == children
     assert all(k.parent == root.id and k.root == root.id for k in kids)
     assert all(root.t0 <= k.t0 <= k.t1 <= root.t1 for k in kids)
@@ -168,16 +174,20 @@ def test_recover_spans_and_counter(make_plan, path, children):
     assert after.get(counter, 0) - totals.get(counter, 0) == 1
     other = "decode.peel" if path == "dense" else "decode.dense"
     assert after.get(other, 0) == totals.get(other, 0)
-    (ev,) = [r for r in recs if isinstance(r, tracing.Count)]
-    assert (ev.name, ev.root) == (counter, root.id)
+    counts = ([] if cached else ["recover.matrix_build"]) + [counter]
+    evs = [r for r in recs if isinstance(r, tracing.Count)]
+    assert [ev.name for ev in evs] == counts
+    assert all(ev.root == root.id for ev in evs)
 
 
-def test_profiler_trace_holds_the_recover_span(tmp_path):
-    plan = _dense_plan()
-    a = jnp.ones((plan.code.R * plan.bm, 8), jnp.float32)
-    out = cm.run(plan, a, jnp.ones((8, 2), jnp.float32))
-    surv = np.arange(1, plan.n_shards)
-    cm.recover(plan, out, surv)
+@pytest.mark.parametrize("cached", [False, True], ids=["miss", "hit"])
+def test_profiler_trace_holds_the_recover_span(tmp_path, cached):
+    warm = _dense_plan()
+    a = jnp.ones((warm.code.R * warm.bm, 8), jnp.float32)
+    out = cm.run(warm, a, jnp.ones((8, 2), jnp.float32))
+    surv = np.arange(1, warm.n_shards)
+    cm.recover(warm, out, surv)
+    plan = warm if cached else _dense_plan()
     jax.profiler.start_trace(str(tmp_path))
     try:
         cm.recover(plan, out, surv).block_until_ready()
@@ -193,8 +203,8 @@ def test_profiler_trace_holds_the_recover_span(tmp_path):
                     if ev.name.startswith(tracing.PREFIX):
                         found[ev.name] = dict(ev.stats)
     assert found["c3p.recover"].get("n_rx") == 7 * plan.blocks_per_shard
-    assert {"c3p.recover.gather", "c3p.decode.pinv",
-            "c3p.decode.solve"} <= set(found)
+    spans = ([] if cached else PLAN_SPANS["dense"]) + ["decode.solve"]
+    assert set(found) == {"c3p.recover"} | {tracing.PREFIX + s for s in spans}
 
 
 def test_horizon_reruns_are_counted(monkeypatch):
